@@ -91,8 +91,12 @@ def _child_rows():
 
 
 def rows():
-    """Spawn the faked-multi-device child and parse its CSV rows."""
+    """Spawn the faked-multi-device child and parse its CSV rows.
+
+    The child measures a faked CPU mesh by design, so it is pinned to the
+    CPU: it must never reach for a chip that its parent may hold."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (
         f"{env.get('XLA_FLAGS', '')} "
         f"--xla_force_host_platform_device_count={N_DEVICES}").strip()

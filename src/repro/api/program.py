@@ -54,7 +54,8 @@ from repro.core.stencil_spec import (StencilSpec, lift_2d_to_3d,
                                      validate_spec)
 from repro.kernels.stencil2d import (ebisu2d, ebisu2d_padded,
                                      padded_shape_2d, strip_geometry)
-from repro.kernels.stencil3d import (_pad_to, ebisu3d, ebisu3d_padded,
+from repro.kernels.stencil3d import (_aligns, _pad_to, ebisu3d,
+                                     ebisu3d_padded,
                                      launch_geometry_3d, padded_shape_3d,
                                      xy_tile)
 from repro.kernels.taps import ghost_extend, tap_sum
@@ -220,29 +221,32 @@ def _tile_request(spec: StencilSpec, t: int, plan: EbisuPlan | None,
 
 def resolve_geometry(spec: StencilSpec, t: int, shape: tuple[int, ...], *,
                      plan: EbisuPlan | None = None,
-                     mode: str = "fused") -> dict:
+                     mode: str = "fused", aligned: bool = False) -> dict:
     """The geometry a one-sweep launch with these args will execute.
 
     Resolves the same tile/grid the kernels resolve (rounding included),
     so modeled traffic is derived from the launch that actually runs —
     not from the plan-less default tile (``fetched_cells``/``body_cells``
-    are the halo-exact input cells and output cells per grid step).
+    are the input cells fetched and output cells per grid step).
+    ``aligned`` is the Mosaic layout (rims rounded to the (8, 128) tile)
+    that every launch lowered for the chip uses.
 
         g = resolve_geometry(get("j2d5pt"), 4, (512, 512))
         g["grid"], g["block"], g["halo"]    # what apply() will launch
     """
     req = _tile_request(spec, t, plan, mode)
     if spec.ndim == 2 and mode != "stream":
-        bh, halo = strip_geometry(spec, t, req["bh"])
-        hp, wp = padded_shape_2d(spec, t, bh, *shape)
-        return dict(grid=(hp // bh,), block=(bh, shape[1]), halo=halo,
-                    padded=(hp, wp),
-                    fetched_cells=(bh + 2 * halo) * wp,
+        bh, rim = strip_geometry(spec, t, req["bh"], aligned)
+        hp, wp = padded_shape_2d(spec, t, bh, *shape, aligned)
+        return dict(grid=(hp // bh,), block=(bh, shape[1]),
+                    halo=spec.halo(t), padded=(hp, wp),
+                    fetched_cells=(bh + 2 * rim) * wp,
                     body_cells=bh * wp)
     if spec.ndim == 2:                   # stream mode: lifted 3-D geometry
         return launch_geometry_3d(lift_2d_to_3d(spec), t,
-                                  (shape[0], 1, shape[1]), **req)
-    return launch_geometry_3d(spec, t, shape, **req)
+                                  (shape[0], 1, shape[1]), aligned=aligned,
+                                  **req)
+    return launch_geometry_3d(spec, t, shape, aligned=aligned, **req)
 
 
 def sweep_once(x: jnp.ndarray, spec: StencilSpec, t: int, *,
@@ -252,15 +256,14 @@ def sweep_once(x: jnp.ndarray, spec: StencilSpec, t: int, *,
                compute_dtype=None) -> jnp.ndarray:
     """One temporally-blocked sweep — the sole plan→kernel dispatch path.
 
-    When a §6 plan is supplied, its decisions are wired all the way into
-    the kernels: tile height/chunk depth (``plan.block``), streaming
-    batch (``plan.lazy_batch``) and DMA pipeline depth
-    (``plan.parallelism.num_buffers``) — none of the planner's outputs
-    are decorative.  ``compute_dtype`` (default float32) is the dtype of
-    the padded compute buffers the kernels run on.
+    When a §6 plan is supplied, its tile height/chunk depth
+    (``plan.block``) and streaming batch (``plan.lazy_batch``) are wired
+    into the kernels; its DMA pipeline depth
+    (``plan.parallelism.num_buffers``) sizes the VMEM model the executor
+    fits tiles with (DESIGN.md §17).  ``compute_dtype`` (default float32)
+    is the dtype of the padded compute buffers the kernels run on.
     """
     lazy = plan.lazy_batch if plan is not None else None
-    nbuf = plan.parallelism.num_buffers if plan is not None else None
     b = None if boundary is None or boundary.is_zero_dirichlet else boundary
     req = _tile_request(spec, t, plan, mode)
     if spec.ndim == 2:
@@ -280,14 +283,14 @@ def sweep_once(x: jnp.ndarray, spec: StencilSpec, t: int, *,
                                          compute_dtype=compute_dtype),
                     taps=spec.taps, t=t)
             y = ebisu3d(x[:, None, :], lift_2d_to_3d(spec), t,
-                        lazy_batch=lazy, num_buffers=nbuf,
+                        lazy_batch=lazy,
                         interpret=interpret, compute_dtype=compute_dtype,
                         **req)
             return y[:, 0, :]
-        return ebisu2d(x, spec, t, mode=mode, num_buffers=nbuf,
+        return ebisu2d(x, spec, t, mode=mode,
                        interpret=interpret, boundary=b,
                        compute_dtype=compute_dtype, **req)
-    return ebisu3d(x, spec, t, lazy_batch=lazy, num_buffers=nbuf,
+    return ebisu3d(x, spec, t, lazy_batch=lazy,
                    interpret=interpret, boundary=b,
                    compute_dtype=compute_dtype, **req)
 
@@ -322,7 +325,7 @@ def _budget(hw: rl.HardwareModel) -> float:
 
 def _sweep_tile_2d(spec: StencilSpec, t: int, shape: tuple[int, int],
                    hw: rl.HardwareModel, plan: EbisuPlan,
-                   interpret: bool = False) -> int:
+                   interpret: bool = False, aligned: bool = False) -> int:
     """Widest strip the §6 VMEM model affords (§6.4: wider before deeper),
     halving toward the plan's tile when the whole domain does not fit.
 
@@ -331,24 +334,25 @@ def _sweep_tile_2d(spec: StencilSpec, t: int, shape: tuple[int, int],
     none, and growing the strip past the plan's block is a measured
     superlinear pessimization on single-threaded CPU hosts (the
     pre-existing ``sweep/j2d5pt-T24`` bench regression — DESIGN.md §17).
+    ``aligned``: the Mosaic strip layout (rims rounded to 8 rows).
     """
     height, width = shape
     halo = spec.halo(t)
     nbuf = plan.parallelism.num_buffers
     floor = max(min(plan.block[0], height), halo)
     if interpret:
-        bh, _ = strip_geometry(spec, t, floor)
+        bh, _ = strip_geometry(spec, t, floor, aligned)
         return bh
-    bh, _ = strip_geometry(spec, t, max(height, halo))
+    bh, _ = strip_geometry(spec, t, max(height, halo), aligned)
     while (vmem_required_2d(spec, t, bh, width, hw.s_cell, nbuf)
            > _budget(hw) and bh // 2 >= floor):
-        bh, _ = strip_geometry(spec, t, bh // 2)
+        bh, _ = strip_geometry(spec, t, bh // 2, aligned)
     return bh
 
 
 def _sweep_tile_3d(spec: StencilSpec, t: int, shape: tuple[int, int, int],
                    hw: rl.HardwareModel, plan: EbisuPlan,
-                   interpret: bool = False
+                   interpret: bool = False, aligned: bool = False
                    ) -> tuple[int, int | None, int | None, int]:
     """Deepest z chunk — and the streaming batch — the §6 VMEM model
     affords at the plan's xy tile.  The batch is fitted with the
@@ -364,10 +368,11 @@ def _sweep_tile_3d(spec: StencilSpec, t: int, shape: tuple[int, int, int],
     halo = spec.halo(t)
     nbuf = plan.parallelism.num_buffers
     ty, tx = plan.block[1], plan.block[2]
-    ty_r, tiled_y = xy_tile(spec, t, ydim, ty)
-    tx_r, tiled_x = xy_tile(spec, t, xdim, tx)
-    ny = ty_r + 2 * halo if tiled_y else ydim
-    nx = tx_r + 2 * halo if tiled_x else xdim
+    ay, ax = _aligns(aligned)
+    ty_r, tiled_y = xy_tile(spec, t, ydim, ty, ay)
+    tx_r, tiled_x = xy_tile(spec, t, xdim, tx, ax)
+    ny = ty_r + 2 * _pad_to(halo, ay) if tiled_y else ydim
+    nx = tx_r + 2 * _pad_to(halo, ax) if tiled_x else xdim
 
     def fit_batch(zc_c: int) -> int | None:
         return fit_streaming_batch(spec, t, zc_c, ny, nx, hw.s_cell,
@@ -415,12 +420,12 @@ def _build_chain(spec: StencilSpec, shape: tuple[int, ...], dtype,
     storage ``dtype``.
     """
     groups = _grouped(sweep_schedule(total_t, depth))
-    nbuf = plan.parallelism.num_buffers
     # interpret-mode strip floor (§17): the plan's own tile beats grown
     # strips on a single-threaded host — EXCEPT under vmap, where the
     # per-strip mask machinery is multiplied by the batch width and the
     # grown strip measures faster; batched chains keep the §6.4 growth
     tile_interp = interpret and not batched
+    aligned = not interpret             # every Mosaic launch is aligned
     repin = boundary.kind in ("periodic", "reflect", "neumann")
     cdtype = jnp.dtype(compute_dtype) if compute_dtype else jnp.float32
     s = tap_sum(spec.taps)
@@ -451,7 +456,8 @@ def _build_chain(spec: StencilSpec, shape: tuple[int, ...], dtype,
         def ext(d: int) -> tuple[int, int]:
             return height + 2 * halo_of(d), width + 2 * halo_of(d)
 
-        cfg = {d: (_sweep_tile_2d(spec, d, ext(d), hw, plan, tile_interp),)
+        cfg = {d: (_sweep_tile_2d(spec, d, ext(d), hw, plan, tile_interp,
+                                  aligned),)
                for d, _ in groups}
 
         def chain(v: jnp.ndarray) -> jnp.ndarray:
@@ -459,12 +465,11 @@ def _build_chain(spec: StencilSpec, shape: tuple[int, ...], dtype,
                 (bh,) = cfg[d]
                 he, we = ext(d)
                 halo = halo_of(d)
-                hp, wp = padded_shape_2d(spec, d, bh, he, we)
+                hp, wp = padded_shape_2d(spec, d, bh, he, we, aligned)
 
                 def sweep(xp, d=d, bh=bh, he=he, we=we):
                     return ebisu2d_padded(xp, spec, d, height=he, width=we,
                                           bh=bh, mode=mode,
-                                          num_buffers=nbuf,
                                           interpret=interpret)
 
                 if repin or affine:
@@ -493,7 +498,8 @@ def _build_chain(spec: StencilSpec, shape: tuple[int, ...], dtype,
             h = halo_of(d)
             return zdim + 2 * h, ydim + 2 * h, xdim + 2 * h
 
-        cfg = {d: _sweep_tile_3d(spec, d, ext3(d), hw, plan, tile_interp)
+        cfg = {d: _sweep_tile_3d(spec, d, ext3(d), hw, plan, tile_interp,
+                                 aligned)
                for d, _ in groups}
 
         def chain(v: jnp.ndarray) -> jnp.ndarray:
@@ -502,14 +508,14 @@ def _build_chain(spec: StencilSpec, shape: tuple[int, ...], dtype,
                 ze, ye, xe = ext3(d)
                 halo = halo_of(d)
                 zp, yp, xp_ = padded_shape_3d(spec, d, (ze, ye, xe), zc=zc,
-                                              ty=ty, tx=tx)
+                                              ty=ty, tx=tx,
+                                              aligned=aligned)
 
                 def sweep(xp, d=d, zc=zc, ty=ty, tx=tx, batch=batch,
                           ze=ze, ye=ye, xe=xe):
                     return ebisu3d_padded(xp, spec, d, zdim=ze, ydim=ye,
                                           xdim=xe, zc=zc, ty=ty, tx=tx,
                                           lazy_batch=batch,
-                                          num_buffers=nbuf,
                                           interpret=interpret)
 
                 if repin or affine:
@@ -542,12 +548,11 @@ def _build_chain(spec: StencilSpec, shape: tuple[int, ...], dtype,
 
 # ------------------------------------------- 2-D donated padded carry ------
 def _padded_chain_2d(xp, spec, total_t, *, t, height, width, bh, mode,
-                     num_buffers, interpret):
+                     interpret):
     assert total_t % t == 0, "padded chaining needs a uniform sweep depth"
     for _ in range(total_t // t):
         xp = ebisu2d_padded(xp, spec, t, height=height, width=width, bh=bh,
-                            mode=mode, num_buffers=num_buffers,
-                            interpret=interpret)
+                            mode=mode, interpret=interpret)
     return xp
 
 
@@ -555,14 +560,13 @@ def _padded_chain_2d(xp, spec, total_t, *, t, height, width, bh, mode,
 def _padded_runner_2d(donate: bool):
     return jax.jit(_padded_chain_2d,
                    static_argnames=("spec", "total_t", "t", "height",
-                                    "width", "bh", "mode", "num_buffers",
-                                    "interpret"),
+                                    "width", "bh", "mode", "interpret"),
                    donate_argnums=(0,) if donate else ())
 
 
 def run_sweeps_padded(xp: jnp.ndarray, spec: StencilSpec, total_t: int, *,
                       t: int, height: int, width: int, bh: int,
-                      mode: str = "fused", num_buffers: int | None = None,
+                      mode: str = "fused",
                       interpret: bool = True) -> jnp.ndarray:
     """Padded-layout sweep chain (2-D, zero Dirichlet), ``t | total_t``.
 
@@ -573,7 +577,7 @@ def run_sweeps_padded(xp: jnp.ndarray, spec: StencilSpec, total_t: int, *,
     importing this module never initializes a JAX backend."""
     return _padded_runner_2d(_supports_donation())(
         xp, spec, total_t, t=t, height=height, width=width, bh=bh,
-        mode=mode, num_buffers=num_buffers, interpret=interpret)
+        mode=mode, interpret=interpret)
 
 
 # ============================================================== programs ==
@@ -754,8 +758,6 @@ class StencilProgram:
         return run_sweeps_padded(
             xp, self.spec, total_t, t=self.t, height=self.shape[0],
             width=self.shape[1], bh=bh, mode=self.mode,
-            num_buffers=(self.plan.parallelism.num_buffers
-                         if self.plan else None),
             interpret=self.interpret)
 
     # ----------------------------------------------- resumable campaigns ----
@@ -830,7 +832,8 @@ class StencilProgram:
         halo, padded layout, halo-exact fetched/body cells)."""
         depth = self.t if t is None else t
         return resolve_geometry(self.spec, depth, self.compute_shape(depth),
-                                plan=self.plan, mode=self.mode)
+                                plan=self.plan, mode=self.mode,
+                                aligned=not self.interpret)
 
     def cost(self, t: int | None = None) -> rl.RooflineResult:
         """§5 practical-attainable estimate at depth ``t``.  At the plan's

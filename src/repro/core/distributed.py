@@ -36,22 +36,6 @@ from repro.core.stencil_spec import StencilSpec
 from repro.kernels.ref import stencil_step
 
 
-def shard_map_compat(f, mesh: Mesh, in_specs, out_specs):
-    """``shard_map`` across jax versions.
-
-    Newer jax exposes ``jax.shard_map`` (with ``check_vma``); the pinned
-    0.4.x toolchain has ``jax.experimental.shard_map`` (with the older
-    ``check_rep`` spelling).  Both checks are disabled: the halo-exchange
-    bodies are intentionally per-shard-divergent (edge shards differ).
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
-
-
 def _axis_size(mesh, ax) -> int:
     if isinstance(ax, str):
         return mesh.shape[ax]
@@ -166,6 +150,8 @@ def make_distributed_stencil(spec: StencilSpec, mesh: Mesh,
             local = ext[tuple(sl)]
         return local
 
-    fn = shard_map_compat(shard_fn, mesh, in_specs=(pspec,),
-                          out_specs=pspec)
+    # check_vma off: the halo-exchange body is per-shard-divergent by
+    # design (edge shards differ)
+    fn = jax.shard_map(shard_fn, mesh=mesh, in_specs=(pspec,),
+                       out_specs=pspec, check_vma=False)
     return jax.jit(fn), pspec

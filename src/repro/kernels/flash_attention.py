@@ -111,7 +111,7 @@ def flash_attention_pallas(q, k, v, *, causal=True, window=None,
         scratch_shapes=[pltpu.VMEM((qc, hd), jnp.float32),
                         pltpu.VMEM((qc, 128), jnp.float32),
                         pltpu.VMEM((qc, 128), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qt, kt, vt)
@@ -230,7 +230,7 @@ def flash_attention_pallas_fwd(q, k, v, *, causal, window, q_chunk,
         scratch_shapes=[pltpu.VMEM((qc, hd), jnp.float32),
                         pltpu.VMEM((qc, 128), jnp.float32),
                         pltpu.VMEM((qc, 128), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qt, kt, vt)
@@ -277,7 +277,7 @@ def flash_attention_pallas_bwd(q, k, v, do, out, lse, *, causal, window,
         scratch_shapes=[pltpu.VMEM((qc, hd), jnp.float32),
                         pltpu.VMEM((kc, hd), jnp.float32),
                         pltpu.VMEM((kc, hd), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(qt, kt, vt, dot, lse, delta)

@@ -41,6 +41,19 @@ This is the paper's Fig. 5/6 scheme on the TPU memory hierarchy, with the
     streaming's "one sync per tile": a grid step has a single pipeline
     boundary regardless of depth ``t``.
 
+**Aligned launches** (``aligned=True``, always when Mosaic lowers the
+kernel): in-plane blocks obey the TPU's (8, 128) tiling — a tiled y
+axis fetches rims of ``round8(halo)`` rows, a tiled x axis rims of
+``round128(halo)`` lanes (z is a leading block axis and keeps exact
+``halo`` rims).  The streamer advances one plane per ``lax.fori_loop``
+iteration through power-of-two rings (the paper's *computing*
+addressing, ``slot = z & (ring − 1)``), with an inner loop over the
+``t`` queues, so the generated code is one plane step whatever ``t`` and
+the chunk depth — the compile takes seconds.  Planes carry one zero
+sublane tile of row margin per side and shift by ``pltpu.roll``
+(``taps.apply_taps_rows``); the in-plane extent is not narrowed per step
+(the overlapped rims absorb the edge error instead).
+
 Boundary semantics: zero outside the domain at every step.  The domain
 sits at ``[0, zdim) × [0, ydim) × [0, xdim)`` of the padded array; the
 per-batch {0,1} validity factors (global-z × global-y × global-x, the
@@ -60,8 +73,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.multiqueue import stream_schedule
+from repro.core.planner import next_pow2
 from repro.core.stencil_spec import StencilSpec
-from repro.kernels.taps import (check_boundary, engine_for,
+from repro.kernels.stencil2d import vmem_limit
+from repro.kernels.taps import (MARGIN, apply_taps_rows, check_boundary,
+                                engine_for, group_by_leading,
                                 is_zero_dirichlet, with_boundary)
 
 
@@ -80,20 +96,27 @@ def chunk_geometry(spec: StencilSpec, t: int, zc: int) -> tuple[int, int]:
     return _pad_to(zc, halo), halo
 
 
-def xy_tile(spec: StencilSpec, t: int, dim: int,
-            tile: int | None) -> tuple[int, bool]:
+def xy_tile(spec: StencilSpec, t: int, dim: int, tile: int | None,
+            align: int = 1) -> tuple[int, bool]:
     """Resolve a requested in-plane tile: (extent, tiled?).
 
-    ``None`` (or a tile that covers the domain once rounded to a halo
-    multiple) means the axis is untiled — full extent, no rim views.
+    The axis' rim is the halo rounded up to ``align`` (8 for y and 128
+    for x on an aligned launch), and the tile is a multiple of the rim.
+    ``None`` (or a tile that covers the domain once rounded) means the
+    axis is untiled — full extent, no rim views.
     """
     if tile is None:
         return dim, False
-    halo = spec.halo(t)
-    tile = _pad_to(max(tile, halo), halo)
+    rim = _pad_to(spec.halo(t), align)
+    tile = _pad_to(max(tile, rim), rim)
     if tile >= dim:
         return dim, False
     return tile, True
+
+
+def _aligns(aligned: bool) -> tuple[int, int]:
+    """(y, x) rim granularity: the f32 (8, 128) tile on aligned launches."""
+    return (8, 128) if aligned else (1, 1)
 
 
 def input_planes_per_chunk(spec: StencilSpec, t: int, zc: int) -> tuple[int, int]:
@@ -104,26 +127,29 @@ def input_planes_per_chunk(spec: StencilSpec, t: int, zc: int) -> tuple[int, int
 
 def launch_geometry_3d(spec: StencilSpec, t: int, shape: tuple[int, int, int],
                        *, zc: int = 16, ty: int | None = None,
-                       tx: int | None = None) -> dict:
+                       tx: int | None = None, aligned: bool = False) -> dict:
     """The geometry a 3-D launch will actually execute (no tracing).
 
     Returns grid, per-grid-step block, halo, per-axis tiled flags, the
-    padded array shape, and the halo-exact fetched/body cell counts per
-    grid step — the quantities the bench's traffic model and the
-    planner-honoring tests consume.
+    padded array shape, and the fetched/body cell counts per grid step
+    (rims rounded to the (8, 128) tile on an ``aligned`` launch, which
+    also fetches whole padded planes on untiled axes) — the quantities
+    the bench's traffic model and the planner-honoring tests consume.
     """
     zdim, ydim, xdim = shape
     zc, halo = chunk_geometry(spec, t, zc)
-    ty_r, tiled_y = xy_tile(spec, t, ydim, ty)
-    tx_r, tiled_x = xy_tile(spec, t, xdim, tx)
+    ay, ax = _aligns(aligned)
+    ty_r, tiled_y = xy_tile(spec, t, ydim, ty, ay)
+    tx_r, tiled_x = xy_tile(spec, t, xdim, tx, ax)
     zp = _pad_to(zdim, zc)
     yp = _pad_to(ydim, ty_r) if tiled_y else _pad_to(ydim, 8)
     xp = _pad_to(xdim, tx_r) if tiled_x else _pad_to(xdim, 128)
     grid = (zp // zc,
             yp // ty_r if tiled_y else 1,
             xp // tx_r if tiled_x else 1)
-    sy = ty_r + 2 * halo if tiled_y else ydim
-    sx = tx_r + 2 * halo if tiled_x else xdim
+    ry, rx = _pad_to(halo, ay), _pad_to(halo, ax)
+    sy = ty_r + 2 * ry if tiled_y else (yp if aligned else ydim)
+    sx = tx_r + 2 * rx if tiled_x else (xp if aligned else xdim)
     fetched = (zc + 2 * halo) * sy * sx
     body = zc * ty_r * tx_r
     return dict(grid=grid, block=(zc, ty_r, tx_r), halo=halo,
@@ -242,42 +268,133 @@ def _stream_kernel(*args, taps, t: int, rad: int, zc: int, halo: int,
                     out_ref[lo - halo:hi - halo] = body.astype(out_ref.dtype)
 
 
+def _stream_kernel_aligned(*args, taps, t: int, rad: int, zc: int,
+                           halo: int, zdim: int, ydim: int, xdim: int,
+                           ty: int, tx: int, ry: int, rx: int,
+                           nyk: int, nxk: int):
+    """The Mosaic body.  The scratch ``q`` is ``(t, ring, MARGIN + SY +
+    MARGIN, SX)``: queue ``s`` holds time-``s`` planes of the haloed
+    ``SY × SX`` working extent, slot ``plane & (ring − 1)``."""
+    refs, out_ref, q = args[:-2], args[-2], args[-1]
+    iz, iy, ix = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    tiled_y, tiled_x = nyk == 3, nxk == 3
+    m = MARGIN
+    ring = q.shape[1]
+    sy, sx = q.shape[2] - 2 * m, q.shape[3]
+    by, bx = out_ref.shape[1], out_ref.shape[2]
+    oy, ox = (ry if tiled_y else 0), (rx if tiled_x else 0)
+    z_base = iz * zc - halo             # global z of strip plane 0
+    y_base = iy * ty - oy
+    x_base = ix * tx - ox
+    groups = group_by_leading(taps)
+
+    def valid(zg, y0=0, x0=0, ny=sy, nx=sx):
+        """Dirichlet validity of the in-plane window at (y0, x0) of a
+        plane at global z ``zg``."""
+        yg = jax.lax.broadcasted_iota(jnp.int32, (ny, nx), 0) + y_base + y0
+        xg = jax.lax.broadcasted_iota(jnp.int32, (ny, nx), 1) + x_base + x0
+        return ((zg >= 0) & (zg < zdim) & (yg >= 0) & (yg < ydim)
+                & (xg >= 0) & (xg < xdim))
+
+    def zero_slot(k, carry):
+        q[k // ring, k % ring] = jnp.zeros(q.shape[2:], q.dtype)
+        return carry
+    jax.lax.fori_loop(0, t * ring, zero_slot, 0)
+
+    ylen = (ry, ty, ry) if tiled_y else (sy,)
+    xlen = (rx, tx, rx) if tiled_x else (sx,)
+    yoff = (0, ry, ry + ty) if tiled_y else (0,)
+    xoff = (0, rx, rx + tx) if tiled_x else (0,)
+
+    def stage(p):
+        """Enqueue strip plane ``p`` (time 0) from its z view."""
+        slot = p & (ring - 1)
+        for zi, lo, n in ((0, 0, halo), (1, halo, zc), (2, halo + zc, halo)):
+            @pl.when((p >= lo) & (p < lo + n))
+            def _(zi=zi, lo=lo):
+                for yi in range(nyk):
+                    for xi in range(nxk):
+                        v = refs[(zi * nyk + yi) * nxk + xi][p - lo]
+                        y0, x0 = yoff[yi], xoff[xi]
+                        ok = valid(z_base + p, y0, x0, ylen[yi], xlen[xi])
+                        q[0, slot, pl.ds(m + y0, ylen[yi]),
+                          pl.ds(x0, xlen[xi])] = jnp.where(ok, v, 0.0)
+
+    def plane(p, carry):
+        stage(p)
+
+        def advance(s, carry):
+            """Time-``s`` plane ``p − s·rad`` from queue ``s − 1``."""
+            qz = p - s * rad
+            acc = None
+            for dz, taps2d in groups:
+                v = q[s - 1, (qz + dz) & (ring - 1)]
+                c = apply_taps_rows(v, taps2d, sy)
+                acc = c if acc is None else acc + c
+            acc = jnp.where(valid(z_base + qz), acc, 0.0)
+
+            @pl.when(s < t)
+            def _():
+                q[s, qz & (ring - 1), pl.ds(m, sy)] = acc
+
+            @pl.when((s == t) & (qz >= halo) & (qz < halo + zc))
+            def _():
+                out_ref[qz - halo] = acc[oy:oy + by, ox:ox + bx].astype(
+                    out_ref.dtype)
+            return carry
+        jax.lax.fori_loop(1, t + 1, advance, 0)
+        return carry
+    jax.lax.fori_loop(0, zc + 2 * halo, plane, 0)
+
+
 def padded_shape_3d(spec: StencilSpec, t: int, shape: tuple[int, int, int],
                     *, zc: int = 16, ty: int | None = None,
-                    tx: int | None = None) -> tuple[int, int, int]:
+                    tx: int | None = None,
+                    aligned: bool = False) -> tuple[int, int, int]:
     """Padded layout a 3-D launch uses (see ``launch_geometry_3d``)."""
-    return launch_geometry_3d(spec, t, shape, zc=zc, ty=ty, tx=tx)["padded"]
+    return launch_geometry_3d(spec, t, shape, zc=zc, ty=ty, tx=tx,
+                              aligned=aligned)["padded"]
 
 
 @functools.partial(jax.jit, static_argnames=(
     "spec", "t", "zdim", "ydim", "xdim", "zc", "ty", "tx", "lazy_batch",
-    "num_buffers", "interpret"))
+    "interpret", "aligned"))
 def ebisu3d_padded(xpad: jnp.ndarray, spec: StencilSpec, t: int, *,
                    zdim: int, ydim: int, xdim: int, zc: int = 16,
                    ty: int | None = None, tx: int | None = None,
                    lazy_batch: int | None = None,
-                   num_buffers: int | None = None,
-                   interpret: bool = True) -> jnp.ndarray:
+                   interpret: bool = True,
+                   aligned: bool | None = None) -> jnp.ndarray:
     """Padded-layout sweep: ``xpad`` is the ``padded_shape_3d`` layout with
     zeros outside the domain at the origin; returns the same layout
-    (out-of-domain cells again zero — DESIGN.md §9.3)."""
+    (out-of-domain cells again zero — DESIGN.md §9.3).
+
+    ``aligned`` selects the Mosaic layout and body; a launch lowered by
+    Mosaic (``interpret=False``) is always aligned, and the interpreter
+    runs the aligned body when asked (how the CPU tests check it).  The
+    aligned body streams one plane per loop iteration, so ``lazy_batch``
+    shapes only the interpreter's schedule."""
     assert spec.ndim == 3
+    aligned = bool(aligned) or not interpret
     rad = spec.radius
     zc, halo = chunk_geometry(spec, t, zc)
-    ty_r, tiled_y = xy_tile(spec, t, ydim, ty)
-    tx_r, tiled_x = xy_tile(spec, t, xdim, tx)
+    ay, ax = _aligns(aligned)
+    ry, rx = _pad_to(halo, ay), _pad_to(halo, ax)
+    ty_r, tiled_y = xy_tile(spec, t, ydim, ty, ay)
+    tx_r, tiled_x = xy_tile(spec, t, xdim, tx, ax)
     kz = zc // halo
     batch, w, _ = stream_schedule(zc, halo, rad,
                                   lazy_batch if lazy_batch else zc)
 
     zp, yp, xp = xpad.shape
     assert (zp, yp, xp) == padded_shape_3d(spec, t, (zdim, ydim, xdim),
-                                           zc=zc, ty=ty, tx=tx), xpad.shape
+                                           zc=zc, ty=ty, tx=tx,
+                                           aligned=aligned), xpad.shape
     grid = (zp // zc,
             yp // ty_r if tiled_y else 1,
             xp // tx_r if tiled_x else 1)
-    nsub_z, nsub_y, nsub_x = zp // halo, yp // halo if tiled_y else 1, \
-        xp // halo if tiled_x else 1
+    nsub_z, nsub_y, nsub_x = zp // halo, yp // ry if tiled_y else 1, \
+        xp // rx if tiled_x else 1
 
     # Per-axis view kinds: rim sub-block before the body, the body, rim
     # after.  Clamped rim ids at the domain edges deliver in-array data
@@ -298,17 +415,17 @@ def ebisu3d_padded(xpad: jnp.ndarray, spec: StencilSpec, t: int, *,
     ykinds = ("top", "mid", "bot") if tiled_y else ("mid",)
     xkinds = ("top", "mid", "bot") if tiled_x else ("mid",)
     zlen = {"top": halo, "mid": zc, "bot": halo}
-    ylen = {"top": halo, "mid": ty_r if tiled_y else yp, "bot": halo}
-    xlen = {"top": halo, "mid": tx_r if tiled_x else xp, "bot": halo}
+    ylen = {"top": ry, "mid": ty_r if tiled_y else yp, "bot": ry}
+    xlen = {"top": rx, "mid": tx_r if tiled_x else xp, "bot": rx}
 
     in_specs = []
     for zk in zkinds:
         fz = z_idx(zk)
         for yk in ykinds:
-            fy = (plane_idx(yk, ty_r // halo, nsub_y) if tiled_y
+            fy = (plane_idx(yk, ty_r // ry, nsub_y) if tiled_y
                   else (lambda j: 0))
             for xk in xkinds:
-                fx = (plane_idx(xk, tx_r // halo, nsub_x) if tiled_x
+                fx = (plane_idx(xk, tx_r // rx, nsub_x) if tiled_x
                       else (lambda k: 0))
                 in_specs.append(pl.BlockSpec(
                     (zlen[zk], ylen[yk], xlen[xk]),
@@ -319,34 +436,36 @@ def ebisu3d_padded(xpad: jnp.ndarray, spec: StencilSpec, t: int, *,
     out_idx = (lambda i, j, k:
                (i, j if tiled_y else 0, k if tiled_x else 0))
 
-    kern = functools.partial(
-        _stream_kernel, taps=spec.taps, t=t, rad=rad, zc=zc, halo=halo,
-        batch=batch, zdim=zdim, ydim=ydim, xdim=xdim, ty=ty_r, tx=tx_r,
-        nyk=len(ykinds), nxk=len(xkinds))
-
-    # VMEM shifting windows, padded to the (8, 128) f32 lane tile when
-    # lowering for real TPU — the unaligned (t, w, ydim, xdim) scratch the
-    # seed allocated only works because interpret mode hides TPU tiling.
-    # The interpreter keeps exact extents: its ref writes are functional
-    # whole-buffer copies, so pad lanes would 4x the per-stage copy cost
-    # for nothing (DESIGN.md §9.2).
-    sy = ty_r + 2 * halo if tiled_y else ydim
-    sx = tx_r + 2 * halo if tiled_x else xdim
-    scr_y, scr_x = (sy, sx) if interpret else (_pad_to(sy, 8),
-                                               _pad_to(sx, 128))
-    scratch = pltpu.VMEM((t, w, scr_y, scr_x), xpad.dtype)
-
     params = {}
-    if not interpret:
-        limit = None
-        if num_buffers is not None:
-            scr = t * w * scr_y * scr_x * 4
-            io = (zc + 2 * halo) * sy * sx * 4 + zc * out_block[1] * \
-                out_block[2] * 4
-            limit = min(128 << 20, max(32 << 20,
-                                       2 * (scr + num_buffers * io)))
-        params["compiler_params"] = pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel",) * 3, vmem_limit_bytes=limit)
+    if aligned:
+        sy = ty_r + 2 * ry if tiled_y else yp
+        sx = tx_r + 2 * rx if tiled_x else xp
+        ring = next_pow2(2 * rad + 1)
+        kern = functools.partial(
+            _stream_kernel_aligned, taps=spec.taps, t=t, rad=rad, zc=zc,
+            halo=halo, zdim=zdim, ydim=ydim, xdim=xdim, ty=ty_r, tx=tx_r,
+            ry=ry, rx=rx, nyk=len(ykinds), nxk=len(xkinds))
+        scratch = pltpu.VMEM((t, ring, sy + 2 * MARGIN, sx), xpad.dtype)
+        # the double-buffered in/out blocks of the Pallas pipeline plus
+        # the queue rings (DESIGN.md §2: the limit's cap comes from the
+        # compile rehearsal)
+        claim = (2 * ((zc + 2 * halo) * sy * sx + zc * out_block[1]
+                      * out_block[2])
+                 + t * ring * (sy + 2 * MARGIN) * sx) * xpad.dtype.itemsize
+        params["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("parallel",) * 3,
+            vmem_limit_bytes=vmem_limit(claim))
+    else:
+        kern = functools.partial(
+            _stream_kernel, taps=spec.taps, t=t, rad=rad, zc=zc, halo=halo,
+            batch=batch, zdim=zdim, ydim=ydim, xdim=xdim, ty=ty_r, tx=tx_r,
+            nyk=len(ykinds), nxk=len(xkinds))
+        # exact-extent shifting windows: the interpreter's ref writes are
+        # functional whole-buffer copies, so pad lanes would only add
+        # copy cost (DESIGN.md §9.2)
+        sy = ty_r + 2 * halo if tiled_y else ydim
+        sx = tx_r + 2 * halo if tiled_x else xdim
+        scratch = pltpu.VMEM((t, w, sy, sx), xpad.dtype)
 
     return pl.pallas_call(
         kern,
@@ -361,14 +480,14 @@ def ebisu3d_padded(xpad: jnp.ndarray, spec: StencilSpec, t: int, *,
 
 
 @functools.partial(jax.jit, static_argnames=("spec", "t", "zc", "ty", "tx",
-                                             "lazy_batch", "num_buffers",
-                                             "interpret", "boundary",
-                                             "compute_dtype"))
+                                             "lazy_batch", "interpret",
+                                             "aligned",
+                                             "boundary", "compute_dtype"))
 def ebisu3d(x: jnp.ndarray, spec: StencilSpec, t: int, *, zc: int = 16,
             ty: int | None = None, tx: int | None = None,
-            lazy_batch: int | None = None, num_buffers: int | None = None,
-            interpret: bool = True, boundary=None,
-            compute_dtype=None) -> jnp.ndarray:
+            lazy_batch: int | None = None,
+            interpret: bool = True, aligned: bool | None = None,
+            boundary=None, compute_dtype=None) -> jnp.ndarray:
     """Apply ``t`` temporally-blocked steps of a 3-D ``spec`` via z-streaming.
 
     ``boundary`` (default: zero Dirichlet) is resolved by reduction to
@@ -383,16 +502,18 @@ def ebisu3d(x: jnp.ndarray, spec: StencilSpec, t: int, *, zc: int = 16,
         return with_boundary(
             x, 3, spec.halo(t), boundary,
             lambda v: ebisu3d(v, spec, t, zc=zc, ty=ty, tx=tx,
-                              lazy_batch=lazy_batch, num_buffers=num_buffers,
-                              interpret=interpret,
+                              lazy_batch=lazy_batch,
+                              interpret=interpret, aligned=aligned,
                               compute_dtype=compute_dtype),
             taps=spec.taps, t=t)
     cdtype = jnp.dtype(compute_dtype) if compute_dtype else jnp.float32
     zdim, ydim, xdim = x.shape
-    zp, yp, xp = padded_shape_3d(spec, t, x.shape, zc=zc, ty=ty, tx=tx)
+    aligned = bool(aligned) or not interpret
+    zp, yp, xp = padded_shape_3d(spec, t, x.shape, zc=zc, ty=ty, tx=tx,
+                                 aligned=aligned)
     xpad = jnp.zeros((zp, yp, xp), cdtype).at[
         :zdim, :ydim, :xdim].set(x.astype(cdtype))
     out = ebisu3d_padded(xpad, spec, t, zdim=zdim, ydim=ydim, xdim=xdim,
                          zc=zc, ty=ty, tx=tx, lazy_batch=lazy_batch,
-                         num_buffers=num_buffers, interpret=interpret)
+                         interpret=interpret, aligned=aligned)
     return out[:zdim, :ydim, :xdim].astype(x.dtype)

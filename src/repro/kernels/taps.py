@@ -15,7 +15,7 @@ masking a kernel still needs is the Dirichlet boundary of the *domain*
 single {0,1} mask built once at strip assembly and applied as one
 multiply per step (DESIGN.md §8.2).
 
-Three application paths:
+Four application paths:
 
   * generic   — pad the tap axes once, then one static slice + FMA per
                 tap.  Works for any tap set (box stencils).
@@ -28,6 +28,12 @@ Three application paths:
                 planes, with zero-fill only in-plane.  Every z-slice is
                 static, so the streamer's batched advance is one
                 vectorized call per temporal step.
+  * aligned rows — for kernels lowered by Mosaic (``apply_taps_rows``):
+                shifts are ``pltpu.roll`` of (8, 128)-aligned values, row
+                context comes from 8-row margins loaded with the rows,
+                and a lane select zeroes what a roll wraps around.  The
+                accumulation order is the star/generic order above, so
+                the result matches the slice paths to rounding.
 
 Every path also supports per-axis **valid-mode** application (``crops``):
 a tap axis with ``crops[a] = c > 0`` is not zero-padded — the output
@@ -44,7 +50,9 @@ from __future__ import annotations
 import functools
 from typing import Sequence
 
+import jax
 import jax.numpy as jnp
+from jax.experimental.pallas import tpu as pltpu
 
 Taps = Sequence[tuple[tuple[int, ...], float]]
 
@@ -158,6 +166,64 @@ def apply_taps_star(x: jnp.ndarray, center: float,
             idx = crop_axes(exclude=a)
             idx[axis] = slice(base + off, base + off + out_a)
             acc = acc + xp[tuple(idx)] * jnp.asarray(c, x.dtype)
+    return acc
+
+
+# Row margin of the aligned path: one f32 sublane tile above and below.
+MARGIN = 8
+
+
+def _row_shift(v: jnp.ndarray, dy: int, rows: int) -> jnp.ndarray:
+    """``out[i] = v[MARGIN + i + dy]`` for ``i < rows`` (``|dy| <= MARGIN``),
+    from the two sublane tiles the shift touches — every slice aligned."""
+    m = MARGIN
+    if dy == 0:
+        return v[m:m + rows]
+    if dy > 0:
+        u = v[m:]
+        return pltpu.roll(u, u.shape[0] - dy, 0)[:rows]
+    u = v[:m + rows]
+    return pltpu.roll(u, -dy, 0)[m:]
+
+
+def _lane_shift(u: jnp.ndarray, dx: int) -> jnp.ndarray:
+    """``out[:, j] = u[:, j + dx]``, zero where ``j + dx`` leaves the row."""
+    if dx == 0:
+        return u
+    w = u.shape[1]
+    col = jax.lax.broadcasted_iota(jnp.int32, u.shape, 1)
+    ok = (col + dx >= 0) & (col + dx < w)
+    return jnp.where(ok, pltpu.roll(u, (-dx) % w, 1), jnp.zeros_like(u))
+
+
+def apply_taps_rows(v: jnp.ndarray, taps2d: Taps, rows: int) -> jnp.ndarray:
+    """One 2-D tap application inside a Mosaic kernel.
+
+    ``v`` is ``(MARGIN + rows + MARGIN, W)``: the ``rows`` to compute plus
+    one sublane tile of row context on each side (zero where the field
+    ends), ``rows`` a multiple of 8 and ``W`` of 128.  Columns are
+    zero-filled at the row ends.  Returns the ``(rows, W)`` result, with
+    the terms summed in the order of ``apply_taps_star`` (star sets) or
+    ``apply_taps_generic``.
+    """
+    dt = v.dtype
+
+    def shifted(dy: int, dx: int) -> jnp.ndarray:
+        return _lane_shift(_row_shift(v, dy, rows), dx)
+
+    star = split_star(taps2d, 2)
+    if star is not None:
+        center, arms = star
+        acc = shifted(0, 0) * jnp.asarray(center, dt)
+        for a, axis_arms in enumerate(arms):
+            for off, c in axis_arms:
+                term = shifted(off, 0) if a == 0 else shifted(0, off)
+                acc = acc + term * jnp.asarray(c, dt)
+        return acc
+    acc = None
+    for (dy, dx), c in taps2d:
+        term = shifted(dy, dx) * jnp.asarray(c, dt)
+        acc = term if acc is None else acc + term
     return acc
 
 

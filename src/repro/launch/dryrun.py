@@ -185,11 +185,7 @@ def run_cell(arch: str, shape_name: str, mesh, mesh_name: str, outdir: str,
                                                  ssm_impl)
             rec["model_flops"] = model_flops(cfg, shape_name)
         ma = compiled.memory_analysis()
-        # jax returns one dict per program executable here on some
-        # versions (a list); normalize to the entry-point dict
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
         cost = hlo_cost.analyze(compiled.as_text())
         terms, dom = roofline_terms(cost, n_chips, mesh.axis_names)
         mf_chip = rec["model_flops"] / n_chips

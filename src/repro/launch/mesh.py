@@ -24,10 +24,6 @@ or from Python, before touching any device::
     from repro.launch.mesh import ensure_fake_devices
     ensure_fake_devices(8)
 
-Version compatibility: ``jax.sharding.AxisType`` (explicit-sharding axis
-annotations) only exists in newer jax; on the pinned 0.4.37 toolchain the
-meshes are built without axis types, which is the classic (fully ``Auto``)
-behavior the shard_map paths assume anyway.
 """
 from __future__ import annotations
 
@@ -35,8 +31,7 @@ import math
 import os
 
 import jax
-import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def ensure_fake_devices(n: int) -> None:
@@ -73,13 +68,8 @@ def _mk(shape, axes) -> Mesh:
     n = math.prod(shape)
     devs = jax.devices()
     assert len(devs) >= n, f"need {n} devices, have {len(devs)}"
-    try:  # newer jax: pin axis types explicitly
-        from jax.sharding import AxisType
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes),
-                             devices=devs[:n])
-    except ImportError:  # jax 0.4.x: no AxisType — plain (Auto) mesh
-        return Mesh(np.asarray(devs[:n]).reshape(shape), axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devs[:n])
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -9,6 +9,7 @@ import jax.numpy as jnp
 
 import repro.configs as C
 from repro.api.attention import attention_cache_stats, attention_program_for
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import transformer
 from repro.models.params import tree_init, tree_shardings
@@ -90,6 +91,7 @@ def main():
     ap.add_argument("--n-data", type=int, default=1)
     ap.add_argument("--n-model", type=int, default=1)
     args = ap.parse_args()
+    use_compile_cache()
     run(args.arch, batch=args.batch, prompt_len=args.prompt_len,
         max_new=args.max_new, reduced=not args.full, n_data=args.n_data,
         n_model=args.n_model)

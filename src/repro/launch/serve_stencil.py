@@ -29,6 +29,7 @@ import sys
 import jax.numpy as jnp
 
 from repro.core.stencil_spec import get
+from repro.launch.compile_cache import use_compile_cache
 from repro.serve.faults import (FaultConfig, FaultInjector, HEALTHY)
 from repro.serve.stencil_service import (ServeError, ServeRequest,
                                          ServiceConfig, ServiceCore,
@@ -183,6 +184,7 @@ def main(argv=None) -> int:
     ap.add_argument("--asyncio", action="store_true",
                     help="drive the real-clock asyncio front door instead")
     args = ap.parse_args(argv)
+    use_compile_cache()
     if args.asyncio:
         import asyncio
         return asyncio.run(run_asyncio(args.requests, seed=args.seed,

@@ -19,7 +19,7 @@ model is printed so the analytic machinery is inspectable:
 (``docs/sharding.md``); on a CPU-only host the device count is faked
 automatically.  ``--distributed`` is the older jnp reference scheme over
 the host mesh; otherwise the compiled program drives the Pallas kernels
-(interpret mode on CPU)."""
+(lowered by Mosaic on a TPU, interpret mode on CPU)."""
 from __future__ import annotations
 
 import argparse
@@ -33,6 +33,7 @@ from repro.api import (Boundary, compile_stencil, define_stencil,
 from repro.core import roofline as rl
 from repro.core.stencil_spec import StencilSpec, TABLE2, get
 from repro.kernels import ref
+from repro.launch.compile_cache import use_compile_cache
 from repro.stencils.data import init_domain, reduced_domain
 
 
@@ -87,8 +88,7 @@ def run_single(spec: StencilSpec | str, *, t: int | None = None,
     # unnormalized Dirichlet admits only depth-1 sweeps (affine closure)
     depth_cap = 1 if (boundary.kind == "dirichlet" and boundary.value != 0.0
                       and abs(spec.tap_sum - 1.0) > 1e-6) else None
-    prog = compile_stencil(spec, shape, boundary=boundary, interpret=True,
-                           t=depth_cap)
+    prog = compile_stencil(spec, shape, boundary=boundary, t=depth_cap)
     depth = t or min(prog.t, 6)
     x = init_domain(spec, shape)
     t0 = time.time()
@@ -147,7 +147,7 @@ def run_sharded(spec: StencilSpec | str, mesh_shape: tuple[int, ...], *,
         cap = min(caps) - (boundary.kind == "reflect") if caps else 6
         t = max(1, min(6, cap))
     prog = compile_stencil(spec, shape, t=t, boundary=boundary,
-                           mesh=mesh_shape, interpret=True)
+                           mesh=mesh_shape)
     total = total_t if total_t is not None else 2 * prog.t + 1
     x = init_domain(spec, shape)
     t0 = time.time()
@@ -198,11 +198,10 @@ def run_campaign_cli(spec: StencilSpec | str, *, checkpoint_dir: str,
             shape[d] = n * max(-(-shape[d] // n), min_shard)
         shape = tuple(shape)
         prog = compile_stencil(spec, shape, t=t or 2, boundary=boundary,
-                               mesh=mesh_shape, interpret=True)
+                               mesh=mesh_shape)
     else:
         shape = reduced_domain(spec, scale)
-        prog = compile_stencil(spec, shape, t=t, boundary=boundary,
-                               interpret=True)
+        prog = compile_stencil(spec, shape, t=t, boundary=boundary)
     total = total_t if total_t is not None else 2 * prog.t + 1
     x = init_domain(spec, shape)
     store = CampaignStore(checkpoint_dir)
@@ -281,8 +280,6 @@ def run_system_cli(name: str, *, t: int | None = None, scale: int = 64,
 
 def run_distributed(name: str, *, t_total: int = 4, t_block: int = 2,
                     scale: int = 64):
-    # lazy: the mesh helpers need jax.sharding.AxisType (newer jax); the
-    # single-device path must keep working without it
     from repro.core.distributed import make_distributed_stencil
     from repro.launch.mesh import make_mesh
 
@@ -376,6 +373,7 @@ def main():
     ap.add_argument("--out", default=None, metavar="FILE",
                     help="np.save the final field to FILE")
     args = ap.parse_args()
+    use_compile_cache()
     if args.taps and args.spec_json:
         ap.error("--taps and --spec-json are mutually exclusive")
     if args.mesh and args.distributed:

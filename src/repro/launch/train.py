@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 
 import repro.configs as C
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import transformer
 from repro.models.params import tree_abstract, tree_init, tree_shardings
@@ -128,6 +129,7 @@ def main():
     ap.add_argument("--n-data", type=int, default=1)
     ap.add_argument("--n-model", type=int, default=1)
     args = ap.parse_args()
+    use_compile_cache()
     _, _, losses = train(
         args.arch, steps=args.steps, batch=args.batch, seq=args.seq,
         reduced=not args.full, ckpt_dir=args.ckpt_dir,

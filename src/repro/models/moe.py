@@ -17,8 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.core.distributed import shard_map_compat
-
 from repro.models.layers import shard
 from repro.models.params import ParamDef
 
@@ -215,6 +213,6 @@ def apply_moe_ep(x, p, *, n_experts: int, n_padded: int, top_k: int,
     if has_gate:
         in_specs.append(wspec)
         args.append(p["w_gate"])
-    fn = shard_map_compat(shard_fn, mesh, in_specs=tuple(in_specs),
-                          out_specs=(P(dp, None, None), P()))
+    fn = jax.shard_map(shard_fn, mesh=mesh, in_specs=tuple(in_specs),
+                       out_specs=(P(dp, None, None), P()), check_vma=False)
     return fn(*args)

@@ -203,7 +203,9 @@ class ServiceConfig:
     ``guard`` is the NaN/Inf output policy; ``batch_widths`` (derived
     when None) are the padded batch sizes the vmapped runner compiles
     for; ``seed`` feeds the backoff jitter RNG (determinism: results
-    never depend on wall clock or unseeded randomness)."""
+    never depend on wall clock or unseeded randomness).  ``interpret``
+    is passed to ``compile_stencil``: ``None`` lowers the kernels by
+    Mosaic on a TPU backend and interprets them elsewhere."""
 
     max_queue: int = 128
     max_inflight_per_tenant: int = 16
@@ -218,7 +220,7 @@ class ServiceConfig:
     max_cells: int = 1 << 22
     max_steps: int = 4096
     default_deadline_ms: float | None = None
-    interpret: bool = True
+    interpret: bool | None = None
     seed: int = 0
 
     def __post_init__(self):

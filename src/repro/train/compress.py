@@ -25,8 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.core.distributed import shard_map_compat
-
 
 def _stochastic_round(x, key):
     lo = jnp.floor(x)
@@ -83,7 +81,8 @@ def make_compressed_allreduce_step(loss_fn, mesh, axis_name="data",
         return jax.tree.map(lambda p, g: p - lr * g.astype(p.dtype),
                             params, grads)
 
-    return shard_map_compat(
-        step, mesh,
+    # jitted: called eagerly, shard_map re-traces the step on every call
+    return jax.jit(jax.shard_map(
+        step, mesh=mesh,
         in_specs=(P(), P(axis_name), P()),
-        out_specs=P())
+        out_specs=P(), check_vma=False))

@@ -8,7 +8,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.core.distributed import shard_map_compat
 from repro.launch.mesh import make_mesh
 from repro.train.compress import (compressed_psum, compressed_psum_tree,
                                   make_compressed_allreduce_step)
@@ -24,8 +23,8 @@ def f(x, key):
     return compressed_psum(x, "data", key)
 
 
-got = jax.jit(shard_map_compat(f, mesh, in_specs=(P("data"), P()),
-                               out_specs=P("data")))(
+got = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(P("data"), P()),
+                            out_specs=P("data"), check_vma=False))(
     xs, jax.random.PRNGKey(1))
 want = jnp.broadcast_to(x.sum(axis=0, keepdims=True), x.shape)
 bound = 8 * float(jnp.abs(x).max()) / 127.0
@@ -36,9 +35,10 @@ print(f"psum err {err:.4f} <= bound {bound:.4f}")
 # ---- unbiasedness: mean over many keys converges to the true sum ---------
 samples = []
 for i in range(64):
-    samples.append(np.asarray(jax.jit(shard_map_compat(
-        f, mesh, in_specs=(P("data"), P()),
-        out_specs=P("data")))(xs, jax.random.PRNGKey(100 + i))))
+    samples.append(np.asarray(jax.jit(jax.shard_map(
+        f, mesh=mesh, in_specs=(P("data"), P()),
+        out_specs=P("data"), check_vma=False))(
+            xs, jax.random.PRNGKey(100 + i))))
 bias = np.abs(np.mean(samples, axis=0) - np.asarray(want)).max()
 assert bias < 0.1 * bound, (bias, bound)
 print(f"bias {bias:.4f} (stochastic rounding unbiased)")

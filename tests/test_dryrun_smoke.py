@@ -32,7 +32,7 @@ def assert_ok_schema(rec):
     assert MEMORY_KEYS <= set(rec["memory"])
     assert set(rec["terms"]) == {"compute_s", "memory_s", "collective_s"}
     assert set(rec["cost_analysis_raw"]) == {"flops", "bytes_accessed"}
-    # normalized scalars, not the raw list jax 0.4.x hands back
+    # normalized scalars
     assert isinstance(rec["cost_analysis_raw"]["flops"], (int, float))
     assert rec["hlo"]["dot_flops"] >= 0
     assert rec["memory"]["peak_per_device"] > 0

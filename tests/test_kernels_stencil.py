@@ -218,3 +218,45 @@ def test_ebisu3d_xy_tiling_plan_wired_end_to_end():
     want = ref.reference_unrolled(x, spec, 2)
     got = ops.ebisu_stencil(x, spec, 2, plan=small, interpret=True)
     _check(got, want, jnp.float32)
+
+
+# ------------------------------------------------ aligned (Mosaic) body -----
+# The body every chip launch runs, checked here through the interpreter:
+# (8, 128)-aligned rims, ping-pong / ring scratch with zero row margins,
+# roll shifts, fori_loop schedules.
+
+@pytest.mark.parametrize("spec", SPECS_2D, ids=lambda s: s.name)
+@pytest.mark.parametrize("shape,t,bh", [((50, 200), 3, 16),
+                                        ((37, 128), 4, 40),
+                                        ((70, 256), 6, 8)])
+def test_ebisu2d_aligned_body_matches_reference(spec, shape, t, bh):
+    from repro.kernels.stencil2d import ebisu2d, input_rows_per_strip
+
+    x = init_domain(spec, shape)
+    want = ref.reference_unrolled(x, spec, t)
+    got = ebisu2d(x, spec, t, bh=bh, interpret=True, aligned=True)
+    _check(got, want, jnp.float32)
+    fetched, body = input_rows_per_strip(spec, t, bh, aligned=True)
+    rim = -(-spec.halo(t) // 8) * 8
+    assert body % rim == 0 and fetched == body + 2 * rim
+
+
+@pytest.mark.parametrize("spec", SPECS_3D, ids=lambda s: s.name)
+@pytest.mark.parametrize("shape,t,tile", [((20, 12, 40), 2, (None, None)),
+                                          ((23, 30, 130), 3, (None, None)),
+                                          ((19, 40, 300), 2, (8, 128))])
+def test_ebisu3d_aligned_body_matches_reference(spec, shape, t, tile):
+    from repro.kernels.stencil3d import ebisu3d, launch_geometry_3d
+
+    x = init_domain(spec, shape)
+    want = ref.reference_unrolled(x, spec, t)
+    ty, tx = tile
+    got = ebisu3d(x, spec, t, zc=4, ty=ty, tx=tx, interpret=True,
+                  aligned=True)
+    _check(got, want, jnp.float32)
+    g = launch_geometry_3d(spec, t, shape, zc=4, ty=ty, tx=tx, aligned=True)
+    assert g["tiled"][1:] == (ty is not None, tx is not None)
+    _, yp, xp = g["padded"]
+    _, by, bx = g["block"]
+    assert yp % 8 == 0 and xp % 128 == 0
+    assert (by % 8, bx % 128) == (0, 0) or ty is None

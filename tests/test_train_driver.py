@@ -7,15 +7,7 @@ import sys
 import numpy as np
 import pytest
 
-try:
-    from repro.launch.train import train
-except ImportError as e:
-    # only the documented incompatibility (jax.sharding.AxisType missing on
-    # older jax) may skip; any other import breakage must surface
-    if "AxisType" not in str(e):
-        raise
-    pytest.skip(f"trainer import unavailable on this jax: {e}",
-                allow_module_level=True)
+from repro.launch.train import train
 
 pytestmark = pytest.mark.slow
 
