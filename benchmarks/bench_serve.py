@@ -6,8 +6,9 @@ dispatch per request.  Rows drive the real :class:`ServiceCore` (real
 monotonic clock — latencies here are wall time, unlike the CLI driver's
 simulated clock) over a fixed seeded burst of requests:
 
-    serve/coalesced-<spec>    us_per_call = wall us per request
-        derived: rps|p99_latency_us|batches|note
+    serve/coalesced-<spec>    us_per_call = wall us per request, from
+        the first submit to the drained stream
+        derived: rps|batches|note
     serve/unbatched-<spec>    the same burst at max_batch=1 (every
         request dispatches alone — the no-coalescing control)
     serve/degraded-<spec>     the same burst under injected faults
@@ -22,6 +23,9 @@ degraded row's completion — both load-resistant.  Rows are persisted to
 """
 from __future__ import annotations
 
+import time
+
+import jax
 import jax.numpy as jnp
 
 from repro.serve.faults import FaultConfig, FaultInjector
@@ -48,27 +52,29 @@ MAX_BATCH = 4
 
 
 def _drive(core: ServiceCore, spec, shape, total_t: int):
-    """Submit the seeded burst, drain, return resolved tickets.
+    """Submit the seeded burst, drain, return the resolved tickets and
+    the stream's wall seconds.
 
-    Inputs are materialized BEFORE the first submit: the rps window runs
-    first-admit -> last-resolve, and building domains inside it would add
-    a constant per-request cost that drowns the batched-vs-solo delta."""
+    Inputs are materialized BEFORE the first submit: the timed stream
+    runs first submit -> drained, and building domains inside it would
+    add a constant per-request cost that drowns the batched-vs-solo
+    delta."""
     fields = [init_domain(spec, shape, seed=i) for i in range(N_REQ)]
+    t0 = time.perf_counter()
     tks = [core.submit(ServeRequest(spec, x, total_t=total_t))
            for x in fields]
     core.drain()
-    return tks
+    jax.block_until_ready([tk.value for tk in tks if tk.ok])
+    return tks, time.perf_counter() - t0
 
 
-def _row(label: str, core: ServiceCore, tickets) -> tuple:
+def _row(label: str, core: ServiceCore, tickets, seconds: float) -> tuple:
     stats = core.stats()
     n_ok = sum(1 for tk in tickets if tk.ok)
     assert all(tk.done for tk in tickets), f"{label}: unresolved tickets"
-    rps = stats.get("requests_per_sec", 0.0)
-    us_per_req = 1e6 / rps if rps else float("inf")
-    return (f"serve/{label}", us_per_req,
+    rps = len(tickets) / seconds
+    return (f"serve/{label}", 1e6 / rps,
             f"rps={rps:.1f}|"
-            f"p99_latency_us={stats.get('p99_latency_ms', 0) * 1e3:.0f}|"
             f"batches={stats.get('batches', 0)}|"
             f"ok={n_ok}/{len(tickets)}|"
             f"note=real-clock-request-stream")
@@ -86,10 +92,10 @@ def _best_rows(scenarios, spec, shape, total_t: int,
     for _ in range(repeats):
         for label, make_core, check in scenarios:
             core = make_core()
-            tks = _drive(core, spec, shape, total_t)
+            tks, seconds = _drive(core, spec, shape, total_t)
             if check is not None:
                 check(core, tks)
-            row = _row(label, core, tks)
+            row = _row(label, core, tks, seconds)
             if label not in best or row[1] < best[label][1]:
                 best[label] = row
     return [best[label] for label, _, _ in scenarios]

@@ -28,6 +28,11 @@ Execution surface:
   * ``geometry(t=None)`` / ``cost(t=None)`` / ``cache_stats()`` —
     introspection: the launch the kernels will resolve, the §5 roofline
     estimate, and the hit/miss counters of the bounded caches.
+  * ``op_phases(T)`` — the compiled ``run`` runner's HLO instructions by
+    the ``stencil.*`` scope of the chain they come from.
+
+The entry points, builds and ``compile_stencil`` carry host spans and
+counters of ``repro.telemetry`` (``docs/architecture.md`` §8).
 
 All module-global state is held in explicit bounded :class:`ProgramCache`
 instances (LRU + counters + ``clear()``) — no unbounded module dicts.
@@ -38,6 +43,7 @@ not import time.
 from __future__ import annotations
 
 import functools
+import itertools
 import math
 import threading
 import warnings
@@ -46,6 +52,7 @@ from collections import OrderedDict
 import jax
 import jax.numpy as jnp
 
+from repro import telemetry
 from repro.api.boundary import ZERO, Boundary
 from repro.core import roofline as rl
 from repro.core.planner import (EbisuPlan, fit_streaming_batch,
@@ -418,6 +425,11 @@ def _build_chain(spec: StencilSpec, shape: tuple[int, ...], dtype,
     All compute buffers are ``compute_dtype`` (the program's policy —
     default float32); only the final result is cast to the program's
     storage ``dtype``.
+
+    Each part runs under a device scope of ``repro.telemetry``:
+    ``stencil.pad``, ``stencil.sweep``, ``stencil.crop`` and
+    ``stencil.cast`` for the zero-copy chain, ``stencil.repin`` for the
+    per-sweep re-pin and affine re-shift.
     """
     groups = _grouped(sweep_schedule(total_t, depth))
     # interpret-mode strip floor (§17): the plan's own tile beats grown
@@ -477,19 +489,27 @@ def _build_chain(spec: StencilSpec, shape: tuple[int, ...], dtype,
                     # ghost halo (periodic/reflect) or re-apply the
                     # affine shift (unnormalized Dirichlet) every sweep
                     for _ in range(count):
-                        xp = jnp.zeros((hp, wp), cdtype).at[:he, :we].set(
-                            ghost_extend(pre(v, d), 2, halo, boundary)
-                            if repin else pre(v, d))
-                        xp = sweep(xp)
-                        v = post(xp[halo:halo + height,
-                                    halo:halo + width], d)
+                        with telemetry.scope("repin"):
+                            xp = jnp.zeros((hp, wp), cdtype).at[
+                                :he, :we].set(
+                                    ghost_extend(pre(v, d), 2, halo,
+                                                 boundary)
+                                    if repin else pre(v, d))
+                        with telemetry.scope("sweep"):
+                            xp = sweep(xp)
+                        with telemetry.scope("repin"):
+                            v = post(xp[halo:halo + height,
+                                        halo:halo + width], d)
                 else:
                     # zero-copy: pad once, chain, crop once (§9.3)
-                    xp = jnp.zeros((hp, wp), cdtype).at[
-                        :height, :width].set(v)
-                    for _ in range(count):
-                        xp = sweep(xp)
-                    v = xp[:height, :width]
+                    with telemetry.scope("pad"):
+                        xp = jnp.zeros((hp, wp), cdtype).at[
+                            :height, :width].set(v)
+                    with telemetry.scope("sweep"):
+                        for _ in range(count):
+                            xp = sweep(xp)
+                    with telemetry.scope("crop"):
+                        v = xp[:height, :width]
             return v
     else:
         zdim, ydim, xdim = shape
@@ -520,28 +540,44 @@ def _build_chain(spec: StencilSpec, shape: tuple[int, ...], dtype,
 
                 if repin or affine:
                     for _ in range(count):
-                        xp = jnp.zeros((zp, yp, xp_), cdtype).at[
-                            :ze, :ye, :xe].set(
-                                ghost_extend(pre(v, d), 3, halo, boundary)
-                                if repin else pre(v, d))
-                        xp = sweep(xp)
-                        v = post(xp[halo:halo + zdim, halo:halo + ydim,
-                                    halo:halo + xdim], d)
+                        with telemetry.scope("repin"):
+                            xp = jnp.zeros((zp, yp, xp_), cdtype).at[
+                                :ze, :ye, :xe].set(
+                                    ghost_extend(pre(v, d), 3, halo,
+                                                 boundary)
+                                    if repin else pre(v, d))
+                        with telemetry.scope("sweep"):
+                            xp = sweep(xp)
+                        with telemetry.scope("repin"):
+                            v = post(xp[halo:halo + zdim, halo:halo + ydim,
+                                        halo:halo + xdim], d)
                 else:
-                    xp = jnp.zeros((zp, yp, xp_), cdtype).at[
-                        :zdim, :ydim, :xdim].set(v)
-                    for _ in range(count):
-                        xp = sweep(xp)
-                    v = xp[:zdim, :ydim, :xdim]
+                    with telemetry.scope("pad"):
+                        xp = jnp.zeros((zp, yp, xp_), cdtype).at[
+                            :zdim, :ydim, :xdim].set(v)
+                    with telemetry.scope("sweep"):
+                        for _ in range(count):
+                            xp = sweep(xp)
+                    with telemetry.scope("crop"):
+                        v = xp[:zdim, :ydim, :xdim]
             return v
 
+    # the casts to and from the compute dtype carry the constant
+    # Dirichlet shift, where there is one
     if boundary.kind == "dirichlet" and boundary.value != 0.0 and not affine:
         def run(x):
-            w = x.astype(cdtype) - shift
-            return (chain(w) + shift).astype(dtype)
+            with telemetry.scope("cast"):
+                w = x.astype(cdtype) - shift
+            v = chain(w)
+            with telemetry.scope("cast"):
+                return (v + shift).astype(dtype)
     else:
         def run(x):
-            return chain(x.astype(cdtype)).astype(dtype)
+            with telemetry.scope("cast"):
+                w = x.astype(cdtype)
+            v = chain(w)
+            with telemetry.scope("cast"):
+                return v.astype(dtype)
 
     return run
 
@@ -621,6 +657,9 @@ class StencilProgram:
         # "record": ...} on a DB hit, {"source": "analytic_fallback"} on
         # a miss, None for programs compiled with an explicit mode
         self.tuned = tuned
+        # call number of run/run_batched/run_sharded: the argument that
+        # ties one call's host spans together in a trace
+        self._calls = itertools.count(1)
 
     # ------------------------------------------------------- execution ----
     def _check(self, x, batched: bool = False):
@@ -653,6 +692,28 @@ class StencilProgram:
                 compute_dtype=self.compute_dtype)))
         return fn(x)
 
+    def _call_runner(self, entry: str, total_t: int, make, call: int, x):
+        """``x`` through the memoized runner ``(entry, total_t)``, made by
+        ``make`` on first use.  That first call, in which JAX traces,
+        lowers and compiles the runner or loads it from the compile
+        cache, is the runner's build: span ``stencil.build`` and the
+        counters ``builds`` and ``build_s``."""
+        built = False
+
+        def build():
+            nonlocal built
+            built = True
+            return make()
+
+        fn = RUNNER_CACHE.get_or_build((self._key, entry, total_t), build)
+        if not built:
+            return fn(x)
+        with telemetry.span("build", entry=entry, t=total_t, call=call), \
+                telemetry.timed("build_s"):
+            y = fn(x)
+        telemetry.add("builds", 1)
+        return y
+
     def _run_fn(self, total_t: int, batched: bool = False):
         plan = self.plan or plan_bucketed(self.spec, self.shape, self.hw)
         depth = max(1, min(self.t, total_t))
@@ -675,13 +736,14 @@ class StencilProgram:
             y = prog.run(x, 64)     # 16 sweeps: pad once, chain, crop
             y = prog.run(x, 10)     # sweeps of depth 4, 4, then 2
         """
-        self._check(x)
-        if total_t == 0:
-            return x
-        fn = RUNNER_CACHE.get_or_build(
-            (self._key, "run", total_t),
-            lambda: jax.jit(self._run_fn(total_t)))
-        return fn(x)
+        call = next(self._calls)
+        with telemetry.span("run", call=call):
+            self._check(x)
+            if total_t == 0:
+                return x
+            return self._call_runner(
+                "run", total_t, lambda: jax.jit(self._run_fn(total_t)),
+                call, x)
 
     def run_batched(self, xs: jnp.ndarray,
                     total_t: int | None = None) -> jnp.ndarray:
@@ -692,14 +754,17 @@ class StencilProgram:
             xs = jnp.stack([x0, x1, x2])        # (3, *prog.shape)
             ys = prog.run_batched(xs, 64)       # one dispatch, 3 fields
         """
-        self._check(xs, batched=True)
-        total_t = self.t if total_t is None else total_t
-        if total_t == 0:
-            return xs
-        fn = RUNNER_CACHE.get_or_build(
-            (self._key, "batched", total_t),
-            lambda: jax.jit(jax.vmap(self._run_fn(total_t, batched=True))))
-        return fn(xs)
+        call = next(self._calls)
+        with telemetry.span("run_batched", call=call):
+            self._check(xs, batched=True)
+            total_t = self.t if total_t is None else total_t
+            if total_t == 0:
+                return xs
+            return self._call_runner(
+                "batched", total_t,
+                lambda: jax.jit(jax.vmap(self._run_fn(total_t,
+                                                      batched=True))),
+                call, xs)
 
     def run_sharded(self, x: jnp.ndarray, total_t: int) -> jnp.ndarray:
         """``total_t`` steps over the program's device mesh, exchanging
@@ -721,24 +786,26 @@ class StencilProgram:
         Requires a program compiled with ``mesh=``; the output is a
         global ``jax.Array`` sharded like the input placement.
         """
-        self._check(x)
-        if self.mesh is None:
-            raise ValueError(
-                "run_sharded needs a mesh-compiled program: "
-                "compile_stencil(spec, shape, mesh=(2, 4)) or mesh=8 — "
-                "see docs/sharding.md")
-        if total_t == 0:
-            return x
-        if self.mesh.size == 1:                 # 1-device mesh: no seams
-            return self.run(x, total_t)
-        from repro.api import sharded
-        fn = RUNNER_CACHE.get_or_build(
-            (self._key, "sharded", total_t),
-            lambda: jax.jit(
-                sharded.build_sharded_runner(self, total_t),
-                donate_argnums=(0,) if _supports_donation() else ()))
-        xs = jax.device_put(x, sharded.operand_sharding(self))
-        return fn(xs)
+        call = next(self._calls)
+        with telemetry.span("run_sharded", call=call):
+            self._check(x)
+            if self.mesh is None:
+                raise ValueError(
+                    "run_sharded needs a mesh-compiled program: "
+                    "compile_stencil(spec, shape, mesh=(2, 4)) or mesh=8 — "
+                    "see docs/sharding.md")
+            if total_t == 0:
+                return x
+            if self.mesh.size == 1:             # 1-device mesh: no seams
+                return self.run(x, total_t)
+            from repro.api import sharded
+            xs = jax.device_put(x, sharded.operand_sharding(self))
+            return self._call_runner(
+                "sharded", total_t,
+                lambda: jax.jit(
+                    sharded.build_sharded_runner(self, total_t),
+                    donate_argnums=(0,) if _supports_donation() else ()),
+                call, xs)
 
     def run_padded(self, xp: jnp.ndarray, total_t: int) -> jnp.ndarray:
         """Uniform-depth padded-layout chain with a donated carry (2-D,
@@ -845,6 +912,24 @@ class StencilProgram:
         return rl.attainable(self.spec, depth, self.hw, rst=True,
                              d_all=math.prod(self.shape))
 
+    def op_phases(self, total_t: int) -> dict[str, str]:
+        """For the compiled runner of ``run(x, total_t)`` on the default
+        device: each HLO instruction name as a profiler trace prints it
+        (``pad.5``, ``slice.23``, ``ebisu2d_t10.12``) -> the ``stencil.*``
+        scope of the chain it belongs to (``stencil.pad``,
+        ``stencil.sweep``, ``stencil.crop``, ``stencil.cast``,
+        ``stencil.repin``).
+
+        Lowers and compiles a copy of the runner (JAX's compile cache
+        serves it where the runner was built before), so call it where
+        the map is asked for, never on the call path.
+
+            prog.op_phases(120)["pad.5"]     # -> 'stencil.pad'
+        """
+        x = jax.ShapeDtypeStruct(self.shape, self.dtype)
+        compiled = jax.jit(self._run_fn(total_t)).lower(x).compile()
+        return telemetry.phases(compiled.as_text())
+
     def cache_stats(self) -> dict:
         """Counters of the module's bounded caches (programs, plans,
         runners) — see :func:`cache_stats`."""
@@ -940,86 +1025,92 @@ def compile_stencil(spec: StencilSpec, shape: tuple[int, ...], *,
         prog = compile_stencil(spec, (256, 512), t=4, mesh=(2, 4))
         y = prog.run_sharded(x, 64)     # one halo exchange per 4 steps
     """
-    validate_spec(spec)
-    shape = tuple(int(n) for n in shape)
-    if len(shape) != spec.ndim:
-        raise ValueError(f"{spec.name} is {spec.ndim}-D; got shape {shape}")
-    tuned_info = None
-    if mode == "tuned":
-        # plan resolution only: the DB record supplies depth, block,
-        # batch AND the kernel family — explicit overrides would make
-        # the record a lie, so they are refused with the fix spelled out
-        if t is not None:
+    with telemetry.span("compile", stencil=spec.name), \
+            telemetry.timed("compile_s"):
+        validate_spec(spec)
+        shape = tuple(int(n) for n in shape)
+        if len(shape) != spec.ndim:
             raise ValueError(
-                "mode='tuned' resolves t from the plan DB; drop t= "
-                "(or compile mode='fused' with an explicit t to pin "
-                "depth yourself)")
-        if not (isinstance(plan, str) and plan == "auto"):
+                f"{spec.name} is {spec.ndim}-D; got shape {shape}")
+        tuned_info = None
+        if mode == "tuned":
+            # plan resolution only: the DB record supplies depth, block,
+            # batch AND the kernel family — explicit overrides would make
+            # the record a lie, so they are refused with the fix spelled out
+            if t is not None:
+                raise ValueError(
+                    "mode='tuned' resolves t from the plan DB; drop t= "
+                    "(or compile mode='fused' with an explicit t to pin "
+                    "depth yourself)")
+            if not (isinstance(plan, str) and plan == "auto"):
+                raise ValueError(
+                    "mode='tuned' resolves the plan from the plan DB; drop "
+                    "plan= (pass an explicit EbisuPlan with mode='fused'/"
+                    "'scratch' to pin tiles yourself)")
+            if mesh is not None:
+                raise ValueError(
+                    "mode='tuned' records are single-device measurements; "
+                    "compile mesh= programs with an explicit mode (the "
+                    "per-shard plan is derived analytically)")
+            from repro.tuning import plandb as _plandb
+            itp = (interpret if interpret is not None
+                   else jax.default_backend() != "tpu")
+            rec = _plandb.resolve_db(plan_db).lookup(
+                spec, shape, "interpret" if itp else "native")
+            if rec is not None:
+                plan = _plandb.plan_from_record(spec, shape, hw, rec)
+                t = plan.t
+                mode = rec["plan"]["exec_mode"]
+                tuned_info = {"source": "plandb", "record": rec}
+            else:
+                mode = "fused"
+                tuned_info = {"source": "analytic_fallback"}
+        valid_modes = ("fused", "scratch", "stream") if spec.ndim == 2 \
+            else ("fused", "scratch")    # 3-D ignores scratch (seed compat)
+        if mode not in valid_modes:
             raise ValueError(
-                "mode='tuned' resolves the plan from the plan DB; drop "
-                "plan= (pass an explicit EbisuPlan with mode='fused'/"
-                "'scratch' to pin tiles yourself)")
+                f"unknown mode {mode!r} for a {spec.ndim}-D spec; "
+                f"expected one of {valid_modes}")
+        boundary = ZERO if boundary is None else boundary
+        cdtype = resolve_compute_dtype(dtype, compute_dtype)
+        if interpret is None:
+            interpret = jax.default_backend() != "tpu"
+        from repro.api import sharded as _sharded
+        mesh = _sharded.resolve_mesh(mesh, spec.ndim)
+        plan_shape = shape
         if mesh is not None:
-            raise ValueError(
-                "mode='tuned' records are single-device measurements; "
-                "compile mesh= programs with an explicit mode (the "
-                "per-shard plan is derived analytically)")
-        from repro.tuning import plandb as _plandb
-        itp = (interpret if interpret is not None
-               else jax.default_backend() != "tpu")
-        rec = _plandb.resolve_db(plan_db).lookup(
-            spec, shape, "interpret" if itp else "native")
-        if rec is not None:
-            plan = _plandb.plan_from_record(spec, shape, hw, rec)
-            t = plan.t
-            mode = rec["plan"]["exec_mode"]
-            tuned_info = {"source": "plandb", "record": rec}
-        else:
-            mode = "fused"
-            tuned_info = {"source": "analytic_fallback"}
-    valid_modes = ("fused", "scratch", "stream") if spec.ndim == 2 \
-        else ("fused", "scratch")        # 3-D ignores scratch (seed compat)
-    if mode not in valid_modes:
-        raise ValueError(f"unknown mode {mode!r} for a {spec.ndim}-D spec; "
-                         f"expected one of {valid_modes}")
-    boundary = ZERO if boundary is None else boundary
-    cdtype = resolve_compute_dtype(dtype, compute_dtype)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    from repro.api import sharded as _sharded
-    mesh = _sharded.resolve_mesh(mesh, spec.ndim)
-    plan_shape = shape
-    if mesh is not None:
-        # shard uniformity first (depth-1 halo fit is a subset of the
-        # full-depth check below), then the per-shard planning pass:
-        # each device is one big tile — plan for the shard it owns, not
-        # the global domain (DESIGN.md §12)
-        _sharded.validate_mesh_for(spec, shape, mesh, 1, boundary)
-        plan_shape = _sharded.shard_extents(shape, mesh)
-    if isinstance(plan, str):
-        if plan != "auto":
-            raise ValueError(f"plan must be an EbisuPlan, None, or 'auto'; "
-                             f"got {plan!r}")
-        plan = plan_bucketed(spec, plan_shape, hw)
-    depth = t if t is not None else (plan.t if plan is not None else 1)
-    if depth < 1:
-        raise ValueError(f"temporal depth must be >= 1, got {depth}")
-    boundary.validate_for(spec, t=depth)
-    if mesh is not None:
-        _sharded.validate_mesh_for(spec, shape, mesh, depth, boundary)
-    key = (spec, shape, jnp.dtype(dtype).name, depth, hw.name,
-           boundary, mode, bool(interpret), _plan_key(plan), cdtype.name,
-           _sharded.mesh_key(mesh),
-           None if tuned_info is None else ("tuned", tuned_info["source"]))
-    cached = PROGRAM_CACHE.get(key)
-    if cached is not None:
-        return cached
-    prog = StencilProgram(key, spec, shape, jnp.dtype(dtype), depth, plan,
-                          hw, boundary, mode, bool(interpret),
-                          compute_dtype=cdtype, mesh=mesh,
-                          tuned=tuned_info)
-    PROGRAM_CACHE.put(key, prog)
-    return prog
+            # shard uniformity first (depth-1 halo fit is a subset of the
+            # full-depth check below), then the per-shard planning pass:
+            # each device is one big tile — plan for the shard it owns, not
+            # the global domain (DESIGN.md §12)
+            _sharded.validate_mesh_for(spec, shape, mesh, 1, boundary)
+            plan_shape = _sharded.shard_extents(shape, mesh)
+        if isinstance(plan, str):
+            if plan != "auto":
+                raise ValueError(
+                    f"plan must be an EbisuPlan, None, or 'auto'; "
+                    f"got {plan!r}")
+            plan = plan_bucketed(spec, plan_shape, hw)
+        depth = t if t is not None else (plan.t if plan is not None else 1)
+        if depth < 1:
+            raise ValueError(f"temporal depth must be >= 1, got {depth}")
+        boundary.validate_for(spec, t=depth)
+        if mesh is not None:
+            _sharded.validate_mesh_for(spec, shape, mesh, depth, boundary)
+        key = (spec, shape, jnp.dtype(dtype).name, depth, hw.name,
+               boundary, mode, bool(interpret), _plan_key(plan), cdtype.name,
+               _sharded.mesh_key(mesh),
+               None if tuned_info is None
+               else ("tuned", tuned_info["source"]))
+        cached = PROGRAM_CACHE.get(key)
+        if cached is not None:
+            return cached
+        prog = StencilProgram(key, spec, shape, jnp.dtype(dtype), depth, plan,
+                              hw, boundary, mode, bool(interpret),
+                              compute_dtype=cdtype, mesh=mesh,
+                              tuned=tuned_info)
+        PROGRAM_CACHE.put(key, prog)
+        return prog
 
 
 def deprecated_entry(name: str, replacement: str) -> None:

@@ -295,6 +295,7 @@ def ebisu2d_padded(xp: jnp.ndarray, spec: StencilSpec, t: int, *,
         out_shape=jax.ShapeDtypeStruct((hp, wp), xp.dtype),
         scratch_shapes=scratch_shapes,
         interpret=interpret,
+        name=f"ebisu2d_t{t}",   # the launch's name in HLO and traces
         **params,
     )(xp, xp, xp)
 

@@ -475,6 +475,7 @@ def ebisu3d_padded(xpad: jnp.ndarray, spec: StencilSpec, t: int, *,
         out_shape=jax.ShapeDtypeStruct((zp, yp, xp), xpad.dtype),
         scratch_shapes=[scratch],
         interpret=interpret,
+        name=f"ebisu3d_t{t}",   # the launch's name in HLO and traces
         **params,
     )(*([xpad] * len(in_specs)))
 

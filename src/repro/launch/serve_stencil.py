@@ -160,9 +160,7 @@ async def run_asyncio(n_requests: int, *, seed: int, rate_hz: float,
     stats = svc.stats()
     ok = sum(1 for r in results if not isinstance(r, ServeError))
     print(f"[serve] asyncio: {ok}/{len(results)} ok, "
-          f"batches={stats.get('batches', 0)}, "
-          f"p99={stats.get('p99_latency_ms', 0)}ms, "
-          f"rps={stats.get('requests_per_sec', 0)}")
+          f"batches={stats.get('batches', 0)}")
     return 0 if len(results) == n_requests else 1
 
 

@@ -64,7 +64,7 @@ dispatches on worker threads (hence the thread-safe ``ProgramCache``).
     await svc.start()
     y = await svc.submit(ServeRequest(spec, x, total_t=16))
     await svc.stop()
-    svc.stats()["p99_latency_ms"]
+    svc.stats()["completed"]
 
 Synchronous/simulated use (the soak test and CLI driver):
 
@@ -273,9 +273,6 @@ class ServiceCore:
         self._programs: dict = {}           # key -> (program, total_t)
         self._tenant_inflight: Counter = Counter()
         self.counters: Counter = Counter()
-        self._latencies_ms: list = []
-        self._first_admit_ms: float | None = None
-        self._last_resolve_ms: float | None = None
 
     def _count(self, name: str, n: int = 1) -> None:
         with self._lock:
@@ -303,8 +300,6 @@ class ServiceCore:
         with self._lock:
             self.counters["admitted"] += 1
             self._tenant_inflight[request.tenant] += 1
-            if self._first_admit_ms is None:
-                self._first_admit_ms = now
             self._programs[key] = (prog, request.total_t)
             self._buckets.setdefault(key, []).append(tk)
         return tk
@@ -623,10 +618,9 @@ class ServiceCore:
             tk.error = error
             tk.done = True
             tk.latency_ms = now - tk.admitted_ms
-            self._last_resolve_ms = now
             if count_admit:
                 self._tenant_inflight[tk.request.tenant] -= 1
-                self._latencies_ms.append(tk.latency_ms)
+                self.counters["resolved"] += 1
                 self.counters["completed" if error is None
                               else "errored"] += 1
         if tk._on_done is not None:
@@ -634,23 +628,15 @@ class ServiceCore:
 
     # --------------------------------------------------------------- stats --
     def stats(self) -> dict:
-        """The service's health report: outcome counters, latency
-        percentiles (service clock), throughput, cache and fault-injector
-        counters — the CLI driver prints this verbatim."""
+        """The service's health report: outcome counters (``resolved``
+        counts admitted requests that reached a result or a typed error),
+        cache and fault-injector counters — the CLI driver prints this
+        verbatim.  A request's latency is on its ticket
+        (``Ticket.latency_ms``); a client times what it waits itself."""
         with self._lock:
-            lat = sorted(self._latencies_ms)
             out = dict(self.counters)
             out["pending"] = sum(len(b) for b in self._buckets.values())
-            out["resolved"] = len(lat)
-            if lat:
-                out["p50_latency_ms"] = round(lat[len(lat) // 2], 3)
-                out["p99_latency_ms"] = round(
-                    lat[min(len(lat) - 1, int(len(lat) * 0.99))], 3)
-                elapsed_ms = ((self._last_resolve_ms or 0)
-                              - (self._first_admit_ms or 0))
-                if elapsed_ms > 0:
-                    out["requests_per_sec"] = round(
-                        len(lat) / (elapsed_ms / 1e3), 2)
+            out["resolved"] = self.counters["resolved"]
             out["runner_cache"] = RUNNER_CACHE.stats()
             if self.faults is not None:
                 out["faults_injected"] = self.faults.stats()

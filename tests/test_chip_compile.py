@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro import telemetry
 from repro.api import compile_stencil
 from repro.core.stencil_spec import get
 
@@ -76,6 +77,28 @@ def test_stencil_sweep_compiles_for_v5e(one_chip, name, path):
     field = 4 * math.prod(lead) * (-(-minor // 128) * 128)
     assert mem.argument_size_in_bytes == field
     assert mem.output_size_in_bytes == field
+
+
+@pytest.mark.parametrize("name", ["j2d5pt", "j3d7pt"])
+def test_op_phases_name_the_chain_for_v5e(one_chip, name):
+    """The phases of the campaign's runner (120 steps) at the published
+    domain, read as ``StencilProgram.op_phases`` reads them, from the
+    compile for the described chip: every sweep launch in
+    ``stencil.sweep`` under the kernel's stable name, and the pad and the
+    crop, where XLA keeps them, in theirs."""
+    spec = get(name)
+    prog = compile_stencil(spec, spec.domain, interpret=False)
+    x = jax.ShapeDtypeStruct(spec.domain, jnp.float32, sharding=one_chip)
+    phases = telemetry.phases(_compile(prog._run_fn(120), x).as_text())
+    kernel = f"ebisu{spec.ndim}d_t{prog.t}"
+    sweep = [op for op, phase in phases.items() if phase == "stencil.sweep"]
+    assert len(sweep) == 120 // prog.t
+    assert all(op.rpartition(".")[0] == kernel for op in sweep)
+    others = {op.rpartition(".")[0]: phase for op, phase in phases.items()
+              if phase != "stencil.sweep"}
+    # j2d5pt's domain is padded to the tile; j3d7pt's already fits it
+    assert others == ({"pad": "stencil.pad", "slice": "stencil.crop"}
+                      if name == "j2d5pt" else {})
 
 
 @pytest.fixture(scope="module")
