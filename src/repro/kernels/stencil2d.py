@@ -37,10 +37,15 @@ TPU mapping of the paper's 2-D scheme (§4.1, §6.3.1, §6.4.1):
     each rim reaches the output — and ``bh`` is a multiple of ``rim``.
     The strip is staged into a ping-pong pair of VMEM buffers with one
     zero sublane tile of margin per side, and each step walks it in
-    8-row chunks under ``lax.fori_loop`` with ``pltpu.roll`` shifts
-    (``taps.apply_taps_rows``).  The generated code is one chunk body,
-    whatever ``t`` and the strip size, so the compile takes seconds;
-    both modes run this body (DESIGN.md §8.4, §8.6).
+    ``CHUNK``-row chunks under ``lax.fori_loop`` with ``pltpu.roll``
+    shifts (``taps.apply_taps_rows``), plus one shorter chunk for the
+    rows left over.  A chunk loads its rows and an 8-row margin on each
+    side, so a taller chunk loads fewer rows per row it computes (64 for
+    48, against 24 for 8); a tap set and width whose tall chunk would
+    spill too much keep 8-row chunks (``chunk_rows``).  The generated
+    code is at most two chunk bodies, whatever ``t`` and the strip size,
+    so the compile takes seconds; both modes run them (DESIGN.md §8.4,
+    §8.6).
 
 Boundary semantics: zero outside the domain at every step (the oracle's
 contract).  The domain sits at rows ``[0, height)`` × cols ``[0, width)``
@@ -63,6 +68,25 @@ from repro.kernels.taps import (MARGIN, apply_taps_rows, check_boundary,
 
 # Largest vmem_limit_bytes a launch asks for (see ``vmem_limit``).
 VMEM_LIMIT_CAP = 128 << 20
+
+# Rows a step of the aligned body computes per loop iteration.  j2d5pt
+# at 8352^2, t=10, per launch on one v5e: 8 rows 4.07 ms, 16 3.72, 32
+# 3.60, 48 3.57, 64 3.63 (DESIGN.md §8.6).
+CHUNK = 48
+# A chunk spills its shifted terms to VMEM, about (rows / 8) x lane tiles
+# x taps vregs of them.  Past this many a CHUNK-row chunk runs out of
+# VMEM (j2d9pt at 16384 lanes) or schedules into more bundles per row than
+# 8-row chunks (j2d25pt at 8704 lanes), and the body keeps one sublane
+# tile per iteration.
+CHUNK_TERMS = 4096
+
+
+def chunk_rows(taps, wp: int) -> int:
+    """Rows per loop iteration of the aligned body for a tap set on a
+    ``wp``-lane strip: ``CHUNK``, or 8 where a ``CHUNK``-row chunk would
+    hold more than ``CHUNK_TERMS`` vregs of shifted terms."""
+    terms = CHUNK // MARGIN * (wp // 128) * len(taps)
+    return CHUNK if terms <= CHUNK_TERMS else MARGIN
 
 
 def _strip_kernel(top_ref, mid_ref, bot_ref, out_ref, *scratch,
@@ -141,10 +165,10 @@ def _strip_kernel_aligned(top_ref, mid_ref, bot_ref, out_ref, buf, *,
     buf[:, 0:m] = zero
     buf[:, m + sh:] = zero
 
-    def domain(r):
-        """Dirichlet validity of strip rows [r, r + 8), all columns."""
-        rows = jax.lax.broadcasted_iota(jnp.int32, (m, wp), 0) + row0 + r
-        cols = jax.lax.broadcasted_iota(jnp.int32, (m, wp), 1)
+    def domain(r, n=m):
+        """Dirichlet validity of strip rows [r, r + n), all columns."""
+        rows = jax.lax.broadcasted_iota(jnp.int32, (n, wp), 0) + row0 + r
+        cols = jax.lax.broadcasted_iota(jnp.int32, (n, wp), 1)
         return (rows >= 0) & (rows < height) & (cols < width)
 
     def chunks(n_rows, body):
@@ -160,14 +184,24 @@ def _strip_kernel_aligned(top_ref, mid_ref, bot_ref, out_ref, buf, *,
                 domain(first + r), ref[pl.ds(r, m), :], 0.0)
         chunks(ref.shape[0], stage)
 
+    ck = chunk_rows(taps, wp)
+    full, tail = divmod(sh, ck)
+
     def sweep_step(s, carry):
         src, dst = (s - 1) % 2, s % 2
 
-        def rows(r):
-            v = buf[src, pl.ds(r, 3 * m), :]      # strip rows r-8..r+16
-            acc = apply_taps_rows(v, taps, m)
-            buf[dst, pl.ds(m + r, m), :] = jnp.where(domain(r), acc, 0.0)
-        chunks(sh, rows)
+        def rows(r, n):
+            v = buf[src, pl.ds(r, n + 2 * m), :]  # strip rows r-8..r+n+8
+            acc = apply_taps_rows(v, taps, n)
+            buf[dst, pl.ds(m + r, n), :] = jnp.where(domain(r, n), acc, 0.0)
+
+        def chunk(c, carry):
+            rows(pl.multiple_of(c * ck, m), ck)
+            return carry
+        if full:
+            jax.lax.fori_loop(0, full, chunk, 0)
+        if tail:
+            rows(full * ck, tail)
         return carry
     jax.lax.fori_loop(1, t + 1, sweep_step, 0)
 

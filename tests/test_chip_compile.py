@@ -11,10 +11,14 @@ The topology is described inside a module-scoped fixture (only the worker
 that runs this file loads the TPU compiler library), and the persistent
 compile cache is off while the fixture is alive: a compile for a
 described chip is written to the cache but cannot be read back without
-one.
+one.  The compiler library is loaded with ``--xla_mosaic_dump_to``, so
+every Mosaic kernel compiled here leaves its passes in a directory the
+tests can count vector ops in.
 """
+import collections
 import math
 import os
+import re
 import time
 
 import jax
@@ -32,11 +36,21 @@ COMPILE_SECONDS = 30.0
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def mosaic_dump(tmp_path_factory):
+    """Where the TPU compiler writes each Mosaic kernel's passes."""
+    return tmp_path_factory.mktemp("mosaic")
+
+
+@pytest.fixture(scope="module")
+def one_chip(mosaic_dump):
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache as cc
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # read once, when the topology description loads the library
+    os.environ["LIBTPU_INIT_ARGS"] = " ".join(
+        filter(None, [os.environ.get("LIBTPU_INIT_ARGS"),
+                      f"--xla_mosaic_dump_to={mosaic_dump}"]))
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
@@ -60,7 +74,8 @@ def _compile(fn, *args):
     return compiled
 
 
-@pytest.mark.parametrize("name", ["j2d5pt", "j2d9pt", "j3d7pt", "j3d13pt"])
+@pytest.mark.parametrize("name", ["j2d5pt", "j2d9pt", "j2d9pt-gol",
+                                  "j2d25pt", "j3d7pt", "j3d13pt"])
 @pytest.mark.parametrize("path", ["apply", "run"])
 def test_stencil_sweep_compiles_for_v5e(one_chip, name, path):
     """One sweep (``apply``) and the multi-sweep chain of ``run`` at the
@@ -77,6 +92,85 @@ def test_stencil_sweep_compiles_for_v5e(one_chip, name, path):
     field = 4 * math.prod(lead) * (-(-minor // 128) * 128)
     assert mem.argument_size_in_bytes == field
     assert mem.output_size_in_bytes == field
+
+
+@pytest.mark.parametrize("name,domain", [("j2d5pt", (2048, 32768)),
+                                         ("j2d9pt", (4096, 16384))])
+def test_wide_2d_sweep_compiles_for_v5e(one_chip, name, domain):
+    """Rows wide enough that a tall chunk's spills would outgrow VMEM:
+    the sweep falls back to 8-row chunks and still lowers."""
+    from repro.kernels.stencil2d import MARGIN, chunk_rows
+
+    spec = get(name)
+    assert chunk_rows(spec.taps, domain[1]) == MARGIN
+    prog = compile_stencil(spec, domain, interpret=False)
+    x = jax.ShapeDtypeStruct(domain, jnp.float32, sharding=one_chip)
+    _compile(lambda v: prog.apply(v), x)
+
+
+# `%12 = tpu.load %buf[...] : memref<...>, vector<8x128xf32>` -> "tpu.load"
+_VECTOR_OP = re.compile(r"^\s*(?:%\S+ = )?([a-z_]+\.[a-z_]+)\b.*vector<")
+
+
+def _sweep_chunk_body(mosaic_dump, kernel, compile_it):
+    """The vector ops, by name, of the loop body that applies the taps to
+    the most rows, in the Mosaic dump of the kernel ``compile_it``
+    compiles.  Each region counts only the ops directly inside it."""
+    pattern = f"*-{kernel}-post-apply-vector-layout-simplify.txt"
+    before = set(mosaic_dump.glob(pattern))
+    compile_it()
+    (dump,) = set(mosaic_dump.glob(pattern)) - before
+    stack, bodies = [collections.Counter()], []
+    for line in dump.read_text().splitlines():
+        text = line.strip()
+        if text.startswith("}"):
+            bodies.append(stack.pop())
+        op = _VECTOR_OP.match(line)
+        if op and not text.startswith("}"):
+            stack[-1][op.group(1)] += 1
+        if text.endswith("{"):
+            stack.append(collections.Counter())
+    return max((b for b in bodies if b["tpu.dynamic_rotate"]),
+               key=lambda b: b["tpu.store"])
+
+
+def test_j2d5pt_launched_strip():
+    """The strip the executor launches j2d5pt's campaign in on the chip:
+    t=10, 272 rows with 16-row rims (304-row strips), 31 of them on an
+    8432 x 8448 padded field."""
+    from repro.api.program import _sweep_tile_2d
+    from repro.core.roofline import TPU_V5E
+    from repro.kernels.stencil2d import padded_shape_2d
+
+    spec = get("j2d5pt")
+    prog = compile_stencil(spec, spec.domain, interpret=False)
+    assert prog.t == 10
+    assert _sweep_tile_2d(spec, 10, spec.domain, TPU_V5E, prog.plan,
+                          aligned=True) == 272
+    assert padded_shape_2d(spec, 10, 272, *spec.domain,
+                           aligned=True) == (8432, 8448)
+
+
+@pytest.mark.parametrize("height,width,padded", [
+    (8352, 8352, (8432, 8448)),   # j2d5pt's domain, as launched
+    (1024, 1024, (1088, 1024)),   # a service shape, no pad lane
+])
+def test_j2d5pt_launch_compiles_in_tall_chunks_for_v5e(one_chip, mosaic_dump,
+                                                       height, width, padded):
+    """j2d5pt's sweep at the executor's strip for t=10: each step computes
+    the strip in chunks of ``CHUNK`` rows, each loading its rows and one
+    8-row margin per side, so it loads 4/3 of a vreg for each vreg it
+    stores where 8-row chunks loaded 3."""
+    from repro.kernels.stencil2d import CHUNK, ebisu2d_padded
+
+    spec = get("j2d5pt")
+    x = jax.ShapeDtypeStruct(padded, jnp.float32, sharding=one_chip)
+    body = _sweep_chunk_body(mosaic_dump, "ebisu2d_t10", lambda: _compile(
+        lambda v: ebisu2d_padded(v, spec, 10, height=height, width=width,
+                                 bh=272, interpret=False), x))
+    vregs = CHUNK // 8 * padded[1] // 128
+    assert body["tpu.store"] == vregs
+    assert body["tpu.load"] * CHUNK == vregs * (CHUNK + 16)
 
 
 @pytest.mark.parametrize("name", ["j2d5pt", "j3d7pt"])

@@ -225,10 +225,20 @@ def test_ebisu3d_xy_tiling_plan_wired_end_to_end():
 # (8, 128)-aligned rims, ping-pong / ring scratch with zero row margins,
 # roll shifts, fori_loop schedules.
 
+# Strips of 24 to 96 rows: a step runs none, one or two full chunks of
+# ``CHUNK`` (48) rows, then a shorter one for the rest, if any.  At 3500
+# columns j2d25pt keeps 8-row chunks (``chunk_rows``).  Widths 254 and 255
+# leave 2 and 1 pad lanes for the lane rolls to wrap into; (20, 130) is
+# shorter than its one strip; heights 50, 37 and 45 end inside a tile.
 @pytest.mark.parametrize("spec", SPECS_2D, ids=lambda s: s.name)
 @pytest.mark.parametrize("shape,t,bh", [((50, 200), 3, 16),
                                         ((37, 128), 4, 40),
-                                        ((70, 256), 6, 8)])
+                                        ((70, 256), 6, 8),
+                                        ((45, 254), 2, 8),
+                                        ((45, 255), 2, 8),
+                                        ((20, 130), 3, 64),
+                                        ((100, 200), 3, 80),
+                                        ((30, 3500), 2, 8)])
 def test_ebisu2d_aligned_body_matches_reference(spec, shape, t, bh):
     from repro.kernels.stencil2d import ebisu2d, input_rows_per_strip
 
