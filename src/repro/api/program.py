@@ -772,10 +772,11 @@ class StencilProgram:
         step (DESIGN.md §12; guide: ``docs/sharding.md``).
 
         Each device holds one uniform shard (mesh axis ``k`` over tensor
-        dim ``k``); per block of depth ``d``, neighbor shards swap
-        ``d·radius``-deep halo slabs (one ``ppermute`` round per sharded
-        dim, corners via two hops) and run the trapezoid-narrowed chain
-        locally.  The whole schedule — remainder block included — is one
+        dim ``k``); per block, neighbor shards swap halo slabs (one
+        ``ppermute`` round per sharded dim, corners via two hops) and
+        each runs the block locally: the 3-D sweep kernel on an
+        edge-aligned window for zero Dirichlet, the trapezoid-narrowed
+        tap chain otherwise.  The whole schedule — remainder block included — is one
         cached jit; the operand buffer is donated to it on backends that
         support donation (pass ``x.copy()`` to keep ``x`` alive there).
         A mesh of total size 1 falls back transparently to :meth:`run`.
@@ -800,12 +801,18 @@ class StencilProgram:
                 return self.run(x, total_t)
             from repro.api import sharded
             xs = jax.device_put(x, sharded.operand_sharding(self))
-            return self._call_runner(
-                "sharded", total_t,
-                lambda: jax.jit(
-                    sharded.build_sharded_runner(self, total_t),
-                    donate_argnums=(0,) if _supports_donation() else ()),
+            y = self._call_runner(
+                "sharded", total_t, lambda: self._sharded_jit(total_t),
                 call, xs)
+            telemetry.add("exchange_rounds", sharded.planned_exchange_rounds(
+                total_t, max(1, min(self.t, total_t))))
+            telemetry.add("halo_bytes", sharded.halo_bytes(self, total_t))
+            return y
+
+    def _sharded_jit(self, total_t: int):
+        from repro.api import sharded
+        return jax.jit(sharded.build_sharded_runner(self, total_t),
+                       donate_argnums=(0,) if _supports_donation() else ())
 
     def run_padded(self, xp: jnp.ndarray, total_t: int) -> jnp.ndarray:
         """Uniform-depth padded-layout chain with a donated carry (2-D,
@@ -914,11 +921,13 @@ class StencilProgram:
 
     def op_phases(self, total_t: int) -> dict[str, str]:
         """For the compiled runner of ``run(x, total_t)`` on the default
-        device: each HLO instruction name as a profiler trace prints it
-        (``pad.5``, ``slice.23``, ``ebisu2d_t10.12``) -> the ``stencil.*``
-        scope of the chain it belongs to (``stencil.pad``,
-        ``stencil.sweep``, ``stencil.crop``, ``stencil.cast``,
-        ``stencil.repin``).
+        device — of ``run_sharded(x, total_t)``, lowered with the
+        operand's ``NamedSharding``, for a mesh program — each HLO
+        instruction name as a profiler trace prints it (``pad.5``,
+        ``slice.23``, ``ebisu2d_t10.12``) -> the ``stencil.*`` scope of
+        the chain it belongs to (``stencil.pad``, ``stencil.sweep``,
+        ``stencil.crop``, ``stencil.cast``, ``stencil.repin``,
+        ``stencil.exchange``).
 
         Lowers and compiles a copy of the runner (JAX's compile cache
         serves it where the runner was built before), so call it where
@@ -926,9 +935,15 @@ class StencilProgram:
 
             prog.op_phases(120)["pad.5"]     # -> 'stencil.pad'
         """
-        x = jax.ShapeDtypeStruct(self.shape, self.dtype)
-        compiled = jax.jit(self._run_fn(total_t)).lower(x).compile()
-        return telemetry.phases(compiled.as_text())
+        if self.mesh is not None and self.mesh.size > 1:
+            from repro.api import sharded
+            x = jax.ShapeDtypeStruct(self.shape, self.dtype,
+                                     sharding=sharded.operand_sharding(self))
+            fn = self._sharded_jit(total_t)
+        else:
+            x = jax.ShapeDtypeStruct(self.shape, self.dtype)
+            fn = jax.jit(self._run_fn(total_t))
+        return telemetry.phases(fn.lower(x).compile().as_text())
 
     def cache_stats(self) -> dict:
         """Counters of the module's bounded caches (programs, plans,
@@ -1092,6 +1107,12 @@ def compile_stencil(spec: StencilSpec, shape: tuple[int, ...], *,
                     f"got {plan!r}")
             plan = plan_bucketed(spec, plan_shape, hw)
         depth = t if t is not None else (plan.t if plan is not None else 1)
+        cap = (_sharded.max_depth(spec, shape, tuple(mesh.shape.values()),
+                                  boundary)
+               if mesh is not None and t is None else None)
+        if cap is not None:
+            # the plan sees one shard, not the neighbor hop its halo takes
+            depth = min(depth, cap)
         if depth < 1:
             raise ValueError(f"temporal depth must be >= 1, got {depth}")
         boundary.validate_for(spec, t=depth)

@@ -10,7 +10,26 @@ PAPERS.md).  Total halo *bytes* are unchanged (depth × 1/frequency), but
 the number of collective rounds — the latency/synchronization term, the
 distributed analogue of Eq 11's grid-sync count — drops by ``t``.
 
-Execution of one temporal block of depth ``d`` (DESIGN.md §12):
+Each shard runs one of two executors (DESIGN.md §12):
+
+**Sweep kernel** (3-D, zero Dirichlet: :func:`uses_kernel`).  Each shard
+keeps a *window* of the global field in ``ebisu3d_padded``'s padded
+layout for the whole call.  On every sharded dim the window has the
+uniform extent ``S + 2h`` (``h = t·radius``) and is aligned to the global
+edge: the first shard's window is ``[0, S+2h)``, the last one's
+``[G−S−2h, G)``, an interior one's ``[o−h, o+S+h)``.  A global edge thus
+always lies on the array edge, where the kernel's own zero fill is the
+exact Dirichlet condition; the error the zero fill makes at a window's
+inner edge travels ``h`` cells in a block and never reaches the shard's
+own cells.  The first exchange round builds the window (``2h``-deep
+slabs); every later round refreshes only the ``h`` cells next to each
+inner edge — the cells the last block left wrong — by slicing them from
+the neighbor's window, ``ppermute``-ing them and writing them in place
+(``lax.dynamic_update_slice``, offsets from ``lax.axis_index``), so no
+block copies the shard.  One layout per call, one crop at the end; a
+shard must hold ``2h`` cells on each sharded dim (:func:`min_shard`).
+
+**Trapezoid** (every other boundary, and 2-D).  Per block of depth ``d``:
 
   1. **deep-halo gather** — for every sharded tensor dim, exchange
      ``h = d·radius``-deep slabs with both mesh neighbors via
@@ -28,14 +47,13 @@ Execution of one temporal block of depth ``d`` (DESIGN.md §12):
      cells that can still reach the block's output, and after ``d`` steps
      the extent is exactly the shard again — gather and crop are the same
      geometry, no separate crop pass.
-  3. **carry** — the result is the next block's input; all blocks of a
-     ``T``-step run live under ONE cached jit (donated on backends that
-     support it), exactly like ``StencilProgram.run``.
+  3. **carry** — the result is the next block's input.
 
-The per-shard compute is the jnp tap-engine chain (the same numerical
-core the Pallas kernels and the oracle share, DESIGN.md §8.3); driving
-the Pallas kernels *inside* shard_map needs a per-shard scalar-prefetch
-origin operand and stays a recorded stretch item (DESIGN.md §17).
+Both run every block of a ``T``-step call under ONE cached jit (the
+operand donated on backends that support it), exactly like
+``StencilProgram.run``, and both exchange dims in sequence with two
+directional ``ppermute``s per sharded dim per block.  Each round runs
+under the device scope ``stencil.exchange``.
 
 Everything here is importable without initializing a JAX backend; device
 questions are answered when ``compile_stencil(..., mesh=)`` resolves the
@@ -43,21 +61,28 @@ mesh.  See ``docs/sharding.md`` for the user-facing guide.
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import telemetry
 from repro.core.distributed import _exchange_one_axis
 from repro.core.stencil_spec import StencilSpec
-from repro.kernels.taps import engine_for, tap_sum
+from repro.kernels.taps import engine_for, is_zero_dirichlet, tap_sum
 
 __all__ = [
     "count_ppermutes",
+    "halo_bytes",
+    "max_depth",
     "mesh_key",
+    "min_shard",
     "planned_exchange_rounds",
     "resolve_mesh",
     "shard_extents",
     "sharded_partition_spec",
+    "uses_kernel",
     "validate_mesh_for",
 ]
 
@@ -126,6 +151,43 @@ def sharded_partition_spec(shape_len: int, mesh: Mesh) -> P:
     return P(*axes)
 
 
+def uses_kernel(spec: StencilSpec, boundary) -> bool:
+    """Whether ``run_sharded`` runs the 3-D sweep kernel on each shard
+    (3-D, zero Dirichlet) rather than the jnp trapezoid."""
+    return spec.ndim == 3 and is_zero_dirichlet(boundary)
+
+
+def min_shard(spec: StencilSpec, t: int, boundary) -> int:
+    """The smallest shard extent on a sharded dim at depth ``t``: the
+    kernel path's edge window takes ``2h`` cells from its neighbor
+    (``h = t·radius``); the trapezoid takes ``h``, and reflect mirrors
+    one more."""
+    h = spec.halo(t)
+    if uses_kernel(spec, boundary):
+        return 2 * h
+    return h + 1 if getattr(boundary, "kind", None) == "reflect" else h
+
+
+def _depth_cap(spec: StencilSpec, shard: int, boundary) -> int:
+    """The deepest ``t`` whose :func:`min_shard` fits ``shard`` cells."""
+    r = spec.radius
+    if uses_kernel(spec, boundary):
+        return shard // (2 * r)
+    if getattr(boundary, "kind", None) == "reflect":
+        return (shard - 1) // r
+    return shard // r
+
+
+def max_depth(spec: StencilSpec, shape: tuple[int, ...],
+              counts: tuple[int, ...], boundary) -> int | None:
+    """The deepest ``t`` every sharded dim holds, with ``counts[k]``
+    shards on dim ``k`` (at least 1: :func:`validate_mesh_for` refuses
+    what even 1 exceeds); ``None`` where no dim is sharded."""
+    caps = [_depth_cap(spec, s // n, boundary)
+            for s, n in zip(shape, counts) if n > 1]
+    return max(1, min(caps)) if caps else None
+
+
 def validate_mesh_for(spec: StencilSpec, shape: tuple[int, ...],
                       mesh: Mesh, t: int, boundary) -> None:
     """Refuse mesh/domain/depth combinations the one-hop deep-halo
@@ -135,6 +197,8 @@ def validate_mesh_for(spec: StencilSpec, shape: tuple[int, ...],
         uniform — XLA's sharded layout requires it);
       * the block halo ``t·radius`` must fit inside one neighbor shard
         (halo slabs travel exactly one ppermute hop per block);
+      * the sweep-kernel path (3-D zero Dirichlet) needs ``2·t·radius``:
+        an edge shard's window reaches that deep into its neighbor;
       * reflect additionally mirrors ``t·radius`` interior cells about
         the edge *excluding* the edge cell, needing one extra row;
       * neumann is not wired into the shard-local edge fills yet —
@@ -148,6 +212,7 @@ def validate_mesh_for(spec: StencilSpec, shape: tuple[int, ...],
             "program single-device where neumann is fully supported")
     dims = _mesh_dims(mesh)
     h = spec.halo(t)
+    need = min_shard(spec, t, boundary)
     for d, n in enumerate(dims):
         if n == 1:
             continue
@@ -158,15 +223,19 @@ def validate_mesh_for(spec: StencilSpec, shape: tuple[int, ...],
                 f"domain to a multiple of {n} or pick a mesh shape that "
                 f"divides {shape[d]} (shards must be uniform)")
         shard = shape[d] // n
-        need = h + 1 if getattr(boundary, "kind", None) == "reflect" else h
         if need > shard:
+            why = ("x 2 (the sweep kernel's edge window) "
+                   if uses_kernel(spec, boundary) else
+                   "(+1 for the reflect mirror) " if need > h else "")
+            t_max = max(1, _depth_cap(spec, shard, boundary))
             raise ValueError(
                 f"{spec.name}: block halo t*radius = {t}*{spec.radius} = {h} "
-                f"{'(+1 for the reflect mirror) ' if need > h else ''}"
-                f"exceeds the shard extent {shard} on dim {d} "
+                f"{why}exceeds the shard extent {shard} on dim {d} "
                 f"({shape[d]} cells / {n} shards) — the deep-halo gather is "
-                f"one neighbor hop per block.  Reduce t, use fewer shards "
-                f"on mesh axis {mesh.axis_names[d]!r}, or grow the domain")
+                f"one neighbor hop per block.  Reduce t to at most "
+                f"{t_max}, use fewer shards on mesh axis "
+                f"{mesh.axis_names[d]!r}, or grow the domain to "
+                f"{n * need} cells on dim {d}")
 
 
 def planned_exchange_rounds(total_t: int, t: int) -> int:
@@ -178,6 +247,35 @@ def planned_exchange_rounds(total_t: int, t: int) -> int:
     """
     from repro.api.program import sweep_schedule
     return len(sweep_schedule(total_t, t))
+
+
+def halo_bytes(prog, total_t: int) -> int:
+    """Bytes the exchange rounds of one ``run_sharded(x, total_t)`` call
+    send between devices, all shards together: per round and sharded dim,
+    one slab each way over every neighbor pair (the first round of the
+    kernel path sends ``2h``-deep slabs, its later ones ``h``)."""
+    from repro.api.program import sweep_schedule
+
+    _, ns, dims = _mesh_layout(prog)
+    shard = shard_extents(prog.shape, prog.mesh)
+    schedule = sweep_schedule(total_t, max(1, min(prog.t, total_t)))
+    kernel = uses_kernel(prog.spec, prog.boundary)
+    ring = getattr(prog.boundary, "kind", None) == "periodic"
+    h = prog.spec.halo(schedule[0]) if schedule else 0
+    window = [n + 2 * h if j in dims else n for j, n in enumerate(shard)]
+    total = 0
+    for k, d in enumerate(schedule):
+        # the extents the round's slabs are cut from, dim after dim
+        ext = window if kernel and k > 0 else list(shard)
+        for dim in dims:
+            deep = (2 * h if k == 0 else h) if kernel else prog.spec.halo(d)
+            cells = deep * math.prod(n for j, n in enumerate(ext) if j != dim)
+            pairs = (ns[dim] if ring else ns[dim] - 1) * \
+                (prog.mesh.size // ns[dim])
+            total += 2 * pairs * cells
+            if k == 0 or not kernel:
+                ext[dim] = window[dim] if kernel else shard[dim] + 2 * deep
+    return total * prog.compute_dtype.itemsize
 
 
 # ====================================================== deep-halo execution ==
@@ -255,35 +353,35 @@ def _dirichlet_post(sharded_dims, axis_names, ns, shard_shape, rad, h):
     return post
 
 
-def build_sharded_runner(prog, total_t: int):
-    """The un-jitted global ``f(x) -> y`` for ``prog.run_sharded(x, T)``.
+def _mesh_layout(prog):
+    """(mesh axis name, shard count) per tensor dim, and the sharded dims."""
+    ndim, mesh = prog.spec.ndim, prog.mesh
+    pad = [None] * (ndim - len(mesh.axis_names))
+    names = list(mesh.axis_names) + pad
+    ns = list(_mesh_dims(mesh)) + [1] * len(pad)
+    return names, ns, [d for d in range(ndim) if ns[d] > 1]
 
-    One shard_map over the program's mesh; inside it, the full multi-
-    block schedule (``sweep_schedule`` — full-depth blocks plus one
-    shallower remainder block) with one deep-halo gather per block and
-    the per-shard trapezoid chain per block.  Compute happens at the
-    program's ``compute_dtype``; only the final result is cast back to
-    storage.  Dirichlet(v≠0) runs through the same affine closure as the
+
+def _trapezoid_shards(prog, total_t: int):
+    """The per-shard ``f(local) -> local`` of the jnp trapezoid: one
+    deep-halo gather and ``d`` narrowed tap-engine steps per block.
+    Dirichlet(v≠0) runs through the same affine closure as the
     single-device chain (DESIGN.md §11.3): the carry is shifted by ``v``
     into zero-Dirichlet space around every block and re-shifted by
     ``v·s^d`` after it — exact when ``s = 1`` (any depth) or ``d = 1``
-    (validated at compile).
-    """
+    (validated at compile)."""
     from repro.api.program import _grouped, sweep_schedule
 
-    spec, mesh, boundary = prog.spec, prog.mesh, prog.boundary
+    spec, boundary = prog.spec, prog.boundary
     depth = max(1, min(prog.t, total_t))
     groups = _grouped(sweep_schedule(total_t, depth))
     rad = spec.radius
     ndim = spec.ndim
-    axis_names = list(mesh.axis_names) + [None] * (ndim - len(mesh.axis_names))
-    ns = list(_mesh_dims(mesh)) + [1] * (ndim - len(mesh.axis_names))
-    sharded_dims = [d for d in range(ndim) if ns[d] > 1]
-    shard_shape = shard_extents(prog.shape, mesh)
+    axis_names, ns, sharded_dims = _mesh_layout(prog)
+    shard_shape = shard_extents(prog.shape, prog.mesh)
     cdtype = prog.compute_dtype
     s = tap_sum(spec.taps)
     engine = engine_for(spec.taps, ndim)
-    pspec = sharded_partition_spec(ndim, mesh)
     dirichlet = boundary.kind == "dirichlet"
     shift = boundary.value if dirichlet else 0.0
 
@@ -294,9 +392,10 @@ def build_sharded_runner(prog, total_t: int):
         if dirichlet and shift != 0.0:
             v = v - jnp.asarray(shift, cdtype)
         ext = v
-        for dim in sharded_dims:
-            ext = _exchange_sharded_axis(ext, dim, h, axis_names[dim],
-                                        ns[dim], boundary)
+        with telemetry.scope("exchange"):
+            for dim in sharded_dims:
+                ext = _exchange_sharded_axis(ext, dim, h, axis_names[dim],
+                                            ns[dim], boundary)
         if dirichlet:
             # unsharded dims stay unextended: the tap engine's zero-fill
             # IS the (shifted) Dirichlet condition at the true array edge
@@ -320,12 +419,148 @@ def build_sharded_runner(prog, total_t: int):
                 v = block(v, d)
         return v
 
+    return shard_fn
+
+
+def _own_offset(i, n: int, h: int):
+    """Where shard ``i``'s own cells start in its edge-aligned window:
+    0 for the first shard, ``2h`` for the last, ``h`` between."""
+    return jnp.where(i == 0, 0, jnp.where(i == n - 1, 2 * h, h))
+
+
+def _kernel_shards(prog, total_t: int, aligned: bool):
+    """The per-shard ``f(local) -> local`` of the sweep-kernel path: the
+    edge-aligned window in the padded layout, ``ebisu3d_padded`` per
+    block, the ``h`` cells at each inner edge refreshed in place between
+    blocks (module docstring)."""
+    from repro.api.program import (_grouped, _sweep_tile_3d, plan_bucketed,
+                                   sweep_schedule)
+    from repro.kernels.stencil3d import ebisu3d_padded, padded_shape_3d
+
+    spec, interpret = prog.spec, prog.interpret
+    depth = max(1, min(prog.t, total_t))
+    groups = _grouped(sweep_schedule(total_t, depth))
+    h = spec.halo(depth)
+    names, ns, sharded_dims = _mesh_layout(prog)
+    shard = shard_extents(prog.shape, prog.mesh)
+    win = tuple(n + 2 * h if d in sharded_dims else n
+                for d, n in enumerate(shard))
+    plan = prog.plan or plan_bucketed(spec, shard, prog.hw)
+    cfg = {d: _sweep_tile_3d(spec, d, win, prog.hw, plan, interpret,
+                             aligned)
+           for d, _ in groups}
+
+    def own(dim: int):
+        return _own_offset(jax.lax.axis_index(names[dim]), ns[dim], h)
+
+    def layout(local: jnp.ndarray) -> jnp.ndarray:
+        """The first round: each sharded dim extended by ``2h``-deep
+        neighbor slabs, then cut to the shard's window."""
+        v = local
+        for dim in sharded_dims:
+            ext = _exchange_sharded_axis(v, dim, 2 * h, names[dim], ns[dim],
+                                        prog.boundary)
+            v = jax.lax.dynamic_slice_in_dim(ext, 2 * h - own(dim),
+                                             win[dim], axis=dim)
+        return v
+
+    def slab(xp: jnp.ndarray, dim: int, start) -> jnp.ndarray:
+        """The ``h`` window cells from ``start`` on ``dim``."""
+        starts = [0] * 3
+        starts[dim] = start
+        sizes = list(win)
+        sizes[dim] = h
+        return jax.lax.dynamic_slice(xp, starts, sizes)
+
+    def put(xp: jnp.ndarray, v: jnp.ndarray, dim: int, start: int):
+        starts = [0] * 3
+        starts[dim] = start
+        return jax.lax.dynamic_update_slice(xp, v, starts)
+
+    def refresh(xp: jnp.ndarray) -> jnp.ndarray:
+        """A later round: the ``h`` cells at each inner window edge, which
+        the last block left wrong, from the neighbor that owns them."""
+        for dim in sharded_dims:
+            n, name, w = ns[dim], names[dim], win[dim]
+            i = jax.lax.axis_index(name)
+            mine = _own_offset(i, n, h)
+            # neighbor i+1's window starts shard - own(i+1) + own(i) cells
+            # into this one; neighbor i-1's ends h + own(i) - own(i-1) in
+            up = slab(xp, dim, shard[dim] + mine - _own_offset(i + 1, n, h))
+            down = slab(xp, dim, h + mine - _own_offset(i - 1, n, h))
+            from_prev = jax.lax.ppermute(up, name,
+                                         [(j, j + 1) for j in range(n - 1)])
+            from_next = jax.lax.ppermute(down, name,
+                                         [(j + 1, j) for j in range(n - 1)])
+            # a global edge has no neighbor: its cells are the shard's own
+            xp = put(xp, jnp.where(i > 0, from_prev, slab(xp, dim, 0)),
+                     dim, 0)
+            xp = put(xp, jnp.where(i < n - 1, from_next,
+                                   slab(xp, dim, w - h)), dim, w - h)
+        return xp
+
+    def shard_fn(local: jnp.ndarray) -> jnp.ndarray:
+        with telemetry.scope("exchange"):
+            v = layout(local)
+        xp, first = None, True
+        for d, count in groups:
+            zc, ty, tx, batch = cfg[d]
+            shape = padded_shape_3d(spec, d, win, zc=zc, ty=ty, tx=tx,
+                                    aligned=aligned)
+            if xp is None or xp.shape != shape:
+                with telemetry.scope("pad"):
+                    if xp is not None:
+                        v = xp[:win[0], :win[1], :win[2]]
+                    xp = jnp.pad(v, [(0, p - n) for p, n in zip(shape, win)])
+            for _ in range(count):
+                if not first:
+                    with telemetry.scope("exchange"):
+                        xp = refresh(xp)
+                first = False
+                with telemetry.scope("sweep"):
+                    xp = ebisu3d_padded(
+                        xp, spec, d, zdim=win[0], ydim=win[1], xdim=win[2],
+                        zc=zc, ty=ty, tx=tx, lazy_batch=batch,
+                        interpret=interpret, aligned=aligned)
+        with telemetry.scope("crop"):
+            starts = [own(dim) if dim in sharded_dims else 0
+                      for dim in range(3)]
+            return jax.lax.dynamic_slice(xp, starts, shard)
+
+    return shard_fn
+
+
+def shard_mapped(prog, shard_fn):
+    """``shard_fn`` over the program's mesh, one shard a device."""
+    pspec = sharded_partition_spec(prog.spec.ndim, prog.mesh)
     # check_vma off: edge shards run a different exchange than interior ones
-    mapped = jax.shard_map(shard_fn, mesh=mesh, in_specs=(pspec,),
-                           out_specs=pspec, check_vma=False)
+    return jax.shard_map(shard_fn, mesh=prog.mesh, in_specs=(pspec,),
+                         out_specs=pspec, check_vma=False)
+
+
+def build_sharded_runner(prog, total_t: int):
+    """The un-jitted global ``f(x) -> y`` for ``prog.run_sharded(x, T)``.
+
+    One shard_map over the program's mesh; inside it, the full multi-
+    block schedule (``sweep_schedule`` — full-depth blocks plus one
+    shallower remainder block), one exchange round per block, and per
+    shard the sweep kernel (:func:`uses_kernel`; its Mosaic layout and
+    body wherever Mosaic lowers it) or the trapezoid chain.  Compute
+    happens at the program's ``compute_dtype``; only the final result is
+    cast back to storage.
+    """
+    if uses_kernel(prog.spec, prog.boundary):
+        shard_fn = _kernel_shards(prog, total_t, not prog.interpret)
+    else:
+        shard_fn = _trapezoid_shards(prog, total_t)
+    mapped = shard_mapped(prog, shard_fn)
 
     def run(x: jnp.ndarray) -> jnp.ndarray:
-        return mapped(x.astype(cdtype)).astype(prog.dtype)
+        with telemetry.scope("cast"):
+            w = x.astype(prog.compute_dtype)
+        y = mapped(w)
+        with telemetry.scope("cast"):
+            return y.astype(prog.dtype)
 
     return run
 

@@ -19,9 +19,8 @@ array, so box-stencil corners arrive via two hops (standard corner trick).
 Per-shard inner compute is the fused jnp blocked step with *global-coordinate*
 masking (axis_index-dependent), which keeps zero-Dirichlet semantics exact at
 the true domain edges while interior shard seams are healed by the halo.  The
-single-device Pallas kernels remain the on-chip realization of the same
-schedule; wiring them inside shard_map needs a per-shard scalar-prefetch
-origin operand (see DESIGN.md §8 — stretch item).
+program front door's ``run_sharded`` (``repro.api.sharded``) runs the 3-D
+sweep kernel inside shard_map on edge-aligned windows (DESIGN.md §12.2).
 """
 from __future__ import annotations
 

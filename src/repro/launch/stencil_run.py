@@ -130,22 +130,20 @@ def run_sharded(spec: StencilSpec | str, mesh_shape: tuple[int, ...], *,
     exchange per temporal block, and (optionally) check against the
     per-step oracle.  Domain dims are rounded up to shard uniformly."""
     from repro.api import planned_exchange_rounds
+    from repro.api.sharded import max_depth, min_shard
 
     spec = get(spec) if isinstance(spec, str) else spec
     boundary = boundary or Boundary.dirichlet(0.0)
     shape = list(reduced_domain(spec, scale))
     for d, n in enumerate(mesh_shape):
         # uniform shards, each wide enough for the deep block halo
-        min_shard = (t or 2) * spec.radius + 1
-        shape[d] = n * max(-(-shape[d] // n), min_shard)
+        shape[d] = n * max(-(-shape[d] // n),
+                           min_shard(spec, t or 2, boundary) + 1)
     shape = tuple(shape)
     if t is None:
         # default depth: run_single's cap, further bounded so the block
-        # halo t*radius fits inside one shard (one neighbor hop)
-        caps = [shape[d] // n // spec.radius
-                for d, n in enumerate(mesh_shape) if n > 1]
-        cap = min(caps) - (boundary.kind == "reflect") if caps else 6
-        t = max(1, min(6, cap))
+        # halo fits the shards (one neighbor hop)
+        t = min(6, max_depth(spec, shape, mesh_shape, boundary) or 6)
     prog = compile_stencil(spec, shape, t=t, boundary=boundary,
                            mesh=mesh_shape)
     total = total_t if total_t is not None else 2 * prog.t + 1
@@ -187,6 +185,7 @@ def run_campaign_cli(spec: StencilSpec | str, *, checkpoint_dir: str,
     """
     import numpy as np
 
+    from repro.api.sharded import min_shard
     from repro.resilient import CampaignStore
 
     spec = get(spec) if isinstance(spec, str) else spec
@@ -194,8 +193,8 @@ def run_campaign_cli(spec: StencilSpec | str, *, checkpoint_dir: str,
     if mesh_shape:
         shape = list(reduced_domain(spec, scale))
         for d, n in enumerate(mesh_shape):
-            min_shard = (t or 2) * spec.radius + 1
-            shape[d] = n * max(-(-shape[d] // n), min_shard)
+            shape[d] = n * max(-(-shape[d] // n),
+                               min_shard(spec, t or 2, boundary) + 1)
         shape = tuple(shape)
         prog = compile_stencil(spec, shape, t=t or 2, boundary=boundary,
                                mesh=mesh_shape)

@@ -10,7 +10,8 @@ that runs the program can be searched for it:
   runner is traced.  It lands in the ``op_name`` metadata of every HLO
   instruction the scoped operators become, and nowhere on the call path.
 * the counters — process-wide seconds and counts the program adds to
-  while it builds (``builds``, ``build_s``, ``compile_s``), read with
+  while it builds (``builds``, ``build_s``, ``compile_s``) and once per
+  ``run_sharded`` call (``exchange_rounds``, ``halo_bytes``), read with
   :func:`snapshot` together with the program caches' own hit and miss
   counters.
 
@@ -34,7 +35,8 @@ import jax
 PREFIX = "stencil."
 
 _lock = threading.Lock()
-_counters = {"builds": 0, "build_s": 0.0, "compile_s": 0.0}
+_counters = {"builds": 0, "build_s": 0.0, "compile_s": 0.0,
+             "exchange_rounds": 0, "halo_bytes": 0}
 
 # `%pad.5 = f32[...] pad(...), ..., metadata={op_name="jit(run)/stencil.pad/
 # scatter" ...}` -> ("pad.5", "jit(run)/stencil.pad/scatter")

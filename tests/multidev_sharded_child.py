@@ -14,7 +14,9 @@ meshes:
     errors.
 
 Domain sizing: dim0 = 8*rad, dim1 = 32*rad (divisible by both meshes,
-shard >= t*rad at every t tested), trailing 3-D dim unsharded and small.
+shard >= t*rad at every t tested), twice that for 3-D zero Dirichlet
+(shard >= 2*t*rad, the sweep-kernel path's window), trailing 3-D dim
+unsharded and small.
 """
 import os
 
@@ -25,7 +27,7 @@ import jax.numpy as jnp
 
 from repro.api import (Boundary, compile_stencil, count_ppermutes,
                        define_stencil, planned_exchange_rounds)
-from repro.api.sharded import build_sharded_runner
+from repro.api.sharded import build_sharded_runner, uses_kernel
 from repro.core.stencil_spec import TABLE2
 from repro.stencils.data import init_domain
 
@@ -38,10 +40,13 @@ CUSTOM = define_stencil(
      ((1, 0), 0.08), ((-1, 0), 0.04)), name="aniso5")  # unnormalized
 
 
-def domain_for(spec, mesh):
-    """Uniform shards on both meshes, shard >= 4*rad (the t=4 halo)."""
+def domain_for(spec, mesh, boundary=None):
+    """Uniform shards on both meshes, shard >= 4*rad (the t=4 halo);
+    shard >= 8*rad where the sweep-kernel path's window needs 2h at t=4
+    (3-D zero Dirichlet)."""
     rad = spec.radius
-    dims = [8 * rad, 32 * rad]
+    grow = 2 if uses_kernel(spec, boundary) else 1
+    dims = [8 * rad * grow, 32 * rad * grow]
     if spec.ndim == 3:
         dims.append(max(2 * rad + 2, 8))
     return tuple(dims)
@@ -51,10 +56,10 @@ def check_equivalence():
     n = 0
     for spec in list(TABLE2.values()) + [CUSTOM]:
         for mesh in MESHES:
-            shape = domain_for(spec, mesh)
-            x = init_domain(spec, shape)
-            for t in DEPTHS:
-                for boundary in BOUNDARIES:
+            for boundary in BOUNDARIES:
+                shape = domain_for(spec, mesh, boundary)
+                x = init_domain(spec, shape)
+                for t in DEPTHS:
                     total = 2 * t + 1      # full, full, remainder
                     prog = compile_stencil(spec, shape, t=t, mesh=mesh,
                                            boundary=boundary,
@@ -79,7 +84,7 @@ def check_exchange_counts():
                                  ("j3d7pt", (1, 8), 2, 6),
                                  ("j2d9pt", (2, 4), 2, 5)):
         spec = TABLE2[name]
-        shape = domain_for(spec, mesh)
+        shape = domain_for(spec, mesh, Boundary.periodic())
         prog = compile_stencil(spec, shape, t=t, mesh=mesh,
                                boundary=Boundary.periodic(), interpret=True)
         fn = build_sharded_runner(prog, total)

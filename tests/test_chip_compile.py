@@ -42,7 +42,8 @@ def mosaic_dump(tmp_path_factory):
 
 
 @pytest.fixture(scope="module")
-def one_chip(mosaic_dump):
+def topo(mosaic_dump):
+    """The described ``v5e:2x2`` host: four chips, none attached."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache as cc
 
@@ -60,9 +61,14 @@ def one_chip(mosaic_dump):
     except Exception as e:  # no TPU compiler library here
         jax.config.update("jax_enable_compilation_cache", was)
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _compile(fn, *args):
@@ -193,6 +199,32 @@ def test_op_phases_name_the_chain_for_v5e(one_chip, name):
     # j2d5pt's domain is padded to the tile; j3d7pt's already fits it
     assert others == ({"pad": "stencil.pad", "slice": "stencil.crop"}
                       if name == "j2d5pt" else {})
+
+
+def test_sharded_kernel_path_compiles_for_v5e_2x2(topo):
+    """j3d7pt-weak2x2: the 5120x576x384 field over a 2x2 mesh of the
+    described host, one j3d7pt Table-2 domain a chip.  Every shard runs
+    the 3-D sweep kernel (the planner's t=8 on the shard) between
+    exchange rounds; a remainder block adds a launch of its own depth."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("shard0", "shard1"))
+    prog = compile_stencil(get("j3d7pt"), (5120, 576, 384), mesh=mesh,
+                           interpret=False)
+    assert prog.t == 8
+    total = prog.t + 1                       # blocks of depth 8 and 1
+    x = jax.ShapeDtypeStruct(prog.shape, jnp.float32, sharding=NamedSharding(
+        mesh, PartitionSpec("shard0", "shard1")))
+    mem = _compile(prog._sharded_jit(total), x).memory_analysis()
+    assert mem.argument_size_in_bytes == 4 * 2560 * 288 * 384   # a shard
+    phases = prog.op_phases(total)
+    sweep = sorted(op.rpartition(".")[0] for op, phase in phases.items()
+                   if phase == "stencil.sweep")
+    assert sweep == ["ebisu3d_t1", "ebisu3d_t8"]
+    exchange = [op for op, phase in phases.items()
+                if phase == "stencil.exchange"]
+    assert any(op.startswith("collective-permute") for op in exchange)
 
 
 @pytest.fixture(scope="module")

@@ -32,6 +32,23 @@ def test_run_sharded_matches_run_on_faked_meshes():
     assert r.stdout.count("refusal") == 3
 
 
+def test_kernel_path_matches_references_on_four_cpu_devices():
+    """3-D zero Dirichlet runs the sweep kernel on every shard
+    (``multidev_sharded_kernel_child.py``): 2x2 and 1x2 meshes, remainder
+    blocks, both kernel bodies, against ``kernels/ref`` and the
+    benchmark's plain reference; ppermute count, op phases, counters."""
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["PYTHONPATH"] = os.path.join(ROOT, "src")
+    r = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "tests",
+                                      "multidev_sharded_kernel_child.py")],
+        env=env, capture_output=True, text=True, timeout=600)
+    assert r.returncode == 0, f"child failed:\n{r.stdout[-4000:]}\n{r.stderr[-4000:]}"
+    assert "ALL-OK" in r.stdout
+    assert r.stdout.count("agreement") == 4
+
+
 # ------------------------------------------------- no-mesh-needed tests ----
 def test_planned_exchange_rounds():
     from repro.api import planned_exchange_rounds
@@ -77,6 +94,39 @@ def test_validate_mesh_for_refusals_without_devices():
     with pytest.raises(ValueError, match="Reduce t"):
         validate_mesh_for(spec, (8, 32), mesh, 8, None)
     validate_mesh_for(spec, (8, 32), mesh, 2, None)   # fits: no raise
+
+
+def test_kernel_path_needs_twice_the_halo():
+    """The sweep-kernel path (3-D zero Dirichlet) refuses a shard under
+    2·t·radius on a dim of 2 shards, with the fix spelled out; other
+    boundaries keep the one-halo rule, and a depth the planner chose is
+    capped to what the shards hold."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from repro.api import Boundary
+    from repro.api.sharded import max_depth, uses_kernel, validate_mesh_for
+    from repro.core.stencil_spec import get
+
+    class _Dev:
+        def __init__(self, i):
+            self.id = i
+
+    mesh = Mesh(np.array([[_Dev(0), _Dev(1)], [_Dev(2), _Dev(3)]]),
+                ("shard0", "shard1"))
+    spec = get("j3d7pt")
+    assert uses_kernel(spec, None) and uses_kernel(spec, Boundary.dirichlet())
+    assert not uses_kernel(spec, Boundary.periodic())
+    assert not uses_kernel(get("j2d5pt"), None)
+    with pytest.raises(ValueError, match=r"x 2 \(the sweep kernel's edge "
+                       r"window\).*Reduce t to at most 3.*grow the domain "
+                       r"to 16 cells on dim 0"):
+        validate_mesh_for(spec, (14, 32, 8), mesh, 4, None)
+    validate_mesh_for(spec, (16, 32, 8), mesh, 4, None)      # 2h fits
+    validate_mesh_for(spec, (14, 32, 8), mesh, 4, Boundary.periodic())
+    assert max_depth(spec, (14, 32, 8), (2, 2), None) == 3
+    assert max_depth(spec, (14, 32, 8), (2, 2), Boundary.periodic()) == 7
+    assert max_depth(spec, (14, 32, 8), (1, 1), None) is None
 
 
 def test_parse_mesh_cli():

@@ -54,7 +54,8 @@ def test_spans_record_nothing_without_a_session(tmp_path):
 def test_snapshot_holds_the_cache_counters():
     _program()
     snap = telemetry.snapshot()
-    assert set(snap) == {"builds", "build_s", "compile_s", "caches"}
+    assert set(snap) == {"builds", "build_s", "compile_s", "exchange_rounds",
+                         "halo_bytes", "caches"}
     assert snap["caches"] == cache_stats()
     assert snap["caches"]["programs"]["hits"] + \
         snap["caches"]["programs"]["misses"] >= 1
