@@ -118,16 +118,24 @@ def test_wide_2d_sweep_compiles_for_v5e(one_chip, name, domain):
 _VECTOR_OP = re.compile(r"^\s*(?:%\S+ = )?([a-z_]+\.[a-z_]+)\b.*vector<")
 
 
+def _mosaic_pass(mosaic_dump, kernel, name, compile_it):
+    """The text of pass ``name`` in the Mosaic dump of the kernel
+    ``compile_it`` compiles."""
+    pattern = f"*-{kernel}-{name}.txt"
+    before = set(mosaic_dump.glob(pattern))
+    compile_it()
+    (dump,) = set(mosaic_dump.glob(pattern)) - before
+    return dump.read_text()
+
+
 def _sweep_chunk_body(mosaic_dump, kernel, compile_it):
     """The vector ops, by name, of the loop body that applies the taps to
     the most rows, in the Mosaic dump of the kernel ``compile_it``
     compiles.  Each region counts only the ops directly inside it."""
-    pattern = f"*-{kernel}-post-apply-vector-layout-simplify.txt"
-    before = set(mosaic_dump.glob(pattern))
-    compile_it()
-    (dump,) = set(mosaic_dump.glob(pattern)) - before
+    text = _mosaic_pass(mosaic_dump, kernel,
+                        "post-apply-vector-layout-simplify", compile_it)
     stack, bodies = [collections.Counter()], []
-    for line in dump.read_text().splitlines():
+    for line in text.splitlines():
         text = line.strip()
         if text.startswith("}"):
             bodies.append(stack.pop())
@@ -177,6 +185,48 @@ def test_j2d5pt_launch_compiles_in_tall_chunks_for_v5e(one_chip, mosaic_dump,
     vregs = CHUNK // 8 * padded[1] // 128
     assert body["tpu.store"] == vregs
     assert body["tpu.load"] * CHUNK == vregs * (CHUNK + 16)
+
+
+def test_j3d7pt_launch_streams_z_for_v5e(one_chip, mosaic_dump):
+    """j3d7pt's sweep as its campaign launches it (t=8, z blocks of 40
+    planes): the z steps of each in-plane column run last and in order
+    (``arbitrary``: the queues carry across them), plus one step that
+    drains the last ``halo`` planes; each step stages one 40-plane input
+    block and no z rims.  Its VMEM limit is no larger than that of a
+    launch staging ``zc + 2·halo`` input planes a step."""
+    from repro.api.program import _sweep_tile_3d
+    from repro.core.roofline import TPU_V5E
+    from repro.kernels.stencil2d import vmem_limit
+    from repro.kernels.stencil3d import ebisu3d_padded
+
+    spec = get("j3d7pt")
+    prog = compile_stencil(spec, spec.domain, interpret=False)
+    zc, ty, tx, _ = _sweep_tile_3d(spec, 8, spec.domain, TPU_V5E, prog.plan,
+                                   aligned=True)
+    assert (zc, ty, tx) == (40, None, None)
+    x = jax.ShapeDtypeStruct(spec.domain, jnp.float32, sharding=one_chip)
+
+    def sweep(v):
+        return ebisu3d_padded(v, spec, 8, zdim=2560, ydim=288, xdim=384,
+                              zc=zc, interpret=False)
+
+    main = _mosaic_pass(mosaic_dump, "ebisu3d_t8", "original",
+                        lambda: _compile(sweep, x))
+    main = next(line for line in main.splitlines()
+                if "func.func @main" in line)
+    assert ("dimension_semantics = [#tpu.dimension_semantics<parallel>, "
+            "#tpu.dimension_semantics<parallel>, "
+            "#tpu.dimension_semantics<arbitrary>]") in main
+    assert "iteration_bounds = array<i64: 1, 1, 65>" in main
+    # the input view, the output block, the t queue rings, the carry
+    assert re.findall(r"memref<([0-9x]+)xf32", main) == [
+        "40x288x384", "40x288x384", "8x4x304x384", "32x288x384"]
+    # the VMEM limit, in the kernel's backend config
+    (limit,) = re.findall(r"\\22size\\22: ([0-9]+)",
+                          jax.jit(sweep).lower(x).as_text())
+    plane, rings = 288 * 384, 8 * 4 * (288 + 16) * 384
+    assert int(limit) <= vmem_limit(
+        4 * (2 * (zc + 16 + zc) * plane + rings))
 
 
 @pytest.mark.parametrize("name", ["j2d5pt", "j3d7pt"])

@@ -251,10 +251,20 @@ def test_ebisu2d_aligned_body_matches_reference(spec, shape, t, bh):
     assert body % rim == 0 and fetched == body + 2 * rim
 
 
+# The z axis streams through carried queues in blocks of ``zc`` planes
+# (4 requested; ``halo`` where the halo is deeper): (37, 12, 40) runs ten
+# blocks and a padded tail; at t=4 every block is one halo deep (no
+# output carry); the tiled cases stream several z blocks per in-plane
+# column, and (24, 20, 260) ends each column with in-domain planes in the
+# rings, which the next column must not read.
 @pytest.mark.parametrize("spec", SPECS_3D, ids=lambda s: s.name)
 @pytest.mark.parametrize("shape,t,tile", [((20, 12, 40), 2, (None, None)),
                                           ((23, 30, 130), 3, (None, None)),
-                                          ((19, 40, 300), 2, (8, 128))])
+                                          ((19, 40, 300), 2, (8, 128)),
+                                          ((37, 12, 40), 2, (None, None)),
+                                          ((21, 12, 40), 4, (None, None)),
+                                          ((24, 20, 260), 2, (8, 128)),
+                                          ((26, 9, 140), 2, (None, 128))])
 def test_ebisu3d_aligned_body_matches_reference(spec, shape, t, tile):
     from repro.kernels.stencil3d import ebisu3d, launch_geometry_3d
 
@@ -266,7 +276,56 @@ def test_ebisu3d_aligned_body_matches_reference(spec, shape, t, tile):
     _check(got, want, jnp.float32)
     g = launch_geometry_3d(spec, t, shape, zc=4, ty=ty, tx=tx, aligned=True)
     assert g["tiled"][1:] == (ty is not None, tx is not None)
-    _, yp, xp = g["padded"]
-    _, by, bx = g["block"]
+    zp, yp, xp = g["padded"]
+    zb, by, bx = g["block"]
     assert yp % 8 == 0 and xp % 128 == 0
     assert (by % 8, bx % 128) == (0, 0) or ty is None
+    halo = spec.halo(t)
+    assert zb % halo == 0 and zp % zb == 0 and g["grid"][0] == zp // zb + 1
+    assert g["plane_steps"] == (zp + halo) * t
+    assert g["z_recompute"] == pytest.approx((zp + halo) / shape[0])
+
+
+@pytest.mark.parametrize("spec", SPECS_2D, ids=lambda s: s.name)
+def test_ebisu3d_aligned_body_streams_lifted_2d(spec):
+    """``mode="stream"`` on the chip: the 2-D field lifted to one row of
+    a 3-D field (``lift_2d_to_3d``) streams its rows as z planes through
+    the aligned body."""
+    from repro.core.stencil_spec import lift_2d_to_3d
+    from repro.kernels.stencil3d import ebisu3d
+
+    t = 3
+    x = init_domain(spec, (45, 200))
+    want = ref.reference_unrolled(x, spec, t)
+    got = ebisu3d(x[:, None, :], lift_2d_to_3d(spec), t, zc=4,
+                  interpret=True, aligned=True)[:, 0, :]
+    _check(got, want, jnp.float32)
+
+
+@pytest.mark.parametrize("shape,window,z_recompute", [
+    ((2560, 288, 384), False, 1.003),   # j3d7pt.campaign's launch
+    ((2576, 304, 384), True, 1.009),    # a 2x2 shard's window, padded
+])
+def test_ebisu3d_aligned_launch_computes_each_plane_once(shape, window,
+                                                         z_recompute):
+    """The j3d7pt launches of both 3-D cells (t=8, halo 8) at the z chunk
+    the executor picks: each plane is computed once a step, up to the
+    padding and the ``halo`` drain planes, where a ``zc + 2·halo`` strip
+    a chunk computed 1.40 (zc 40) and 1.50 (zc 32) times the planes."""
+    from repro.api.program import _sweep_tile_3d, plan_bucketed
+    from repro.core import roofline as rl
+    from repro.kernels.stencil3d import launch_geometry_3d
+
+    spec = get("j3d7pt")
+    p = plan_bucketed(spec, (2560, 288, 384), rl.TPU_V5E) if window \
+        else _plan_for(spec)
+    zc, ty, tx, _ = _sweep_tile_3d(spec, 8, shape, rl.TPU_V5E, p,
+                                   aligned=True)
+    assert (zc, ty, tx) == ((32 if window else 40), None, None)
+    g = launch_geometry_3d(spec, 8, shape, zc=zc, aligned=True)
+    assert g["z_recompute"] == pytest.approx(z_recompute, abs=5e-4)
+    zp, yp, xp = g["padded"]
+    assert g["plane_steps"] == (zp + 8) * 8
+    assert g["fetched_cells"] == g["body_cells"] == zc * yp * xp
+    rimmed = launch_geometry_3d(spec, 8, shape, zc=zc)
+    assert rimmed["z_recompute"] == pytest.approx((zc + 16) / zc, abs=0.01)
